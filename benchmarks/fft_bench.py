@@ -102,7 +102,7 @@ def _iters_for(n: int) -> int:
 
 
 def bench_size(n: int) -> dict:
-    from jax.experimental import enable_x64
+    from repro._x64 import enable_x64
 
     rng = np.random.default_rng(0)
     real64 = rng.standard_normal((n, n))

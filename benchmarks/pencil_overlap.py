@@ -2,63 +2,75 @@
 
 The ping-pong insight applied to the collective itself (DESIGN.md §2):
 slab i's all_to_all is independent of slab i−1's column FFT, so the
-scheduler can overlap them. Runs in a subprocess with 8 fake devices;
-reports wall-clock plus the compiled collective schedule structure.
+scheduler can overlap them. Runs in the calling process on every device
+``jax.devices()`` reports (on the CPU, fake several with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before JAX starts);
+reports wall-clock plus the compiled collective schedule structure, and
+raises when the overlapped result disagrees with numpy.
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import emit
 
-_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import time
-import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
-from repro.core.distributed import fft2_pencil, fft2_pencil_overlapped, pencil_sharding
+#: Max relative error of the overlapped pencil FFT against numpy.fft.fft2.
+REL_ERR_GATE = 1e-4
 
-mesh = make_mesh((8,), ("data",))
-rng = np.random.default_rng(0)
-x = rng.standard_normal((1024, 1024)).astype(np.float32)
-xs = jax.device_put(jnp.asarray(x), pencil_sharding(mesh, "data", "rows"))
-
-plain = jax.jit(lambda v: fft2_pencil(v, mesh, variant="stockham"))
-over = jax.jit(lambda v: fft2_pencil_overlapped(v, mesh, variant="stockham", chunks=4))
-
-for name, fn in (("plain", plain), ("overlapped", over)):
-    jax.block_until_ready(fn(xs))
-    ts = []
-    for _ in range(5):
-        t0 = time.perf_counter(); jax.block_until_ready(fn(xs))
-        ts.append(time.perf_counter() - t0)
-    hlo = fn.lower(xs).compile().as_text()
-    n_a2a = sum(1 for l in hlo.splitlines() if "all-to-all" in l and "=" in l)
-    print(f"{name},{sorted(ts)[2]*1e6:.1f},a2a_ops={n_a2a}")
-ref = np.fft.fft2(x)
-got = np.asarray(over(xs))
-print(f"overlap_rel_err,{np.max(np.abs(got-ref))/np.max(np.abs(ref)):.2e},")
-"""
+#: Frame edge of the square float32 input.
+SIZE = 1024
 
 
 def run():
-    print("# Distributed pencil FFT: corner-turn overlap (8 fake devices)")
-    env = dict(os.environ, PYTHONPATH="src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SCRIPT], capture_output=True, text=True, env=env,
-        timeout=900,
+    from repro.core.distributed import (
+        fft2_pencil,
+        fft2_pencil_overlapped,
+        pencil_sharding,
     )
-    if out.returncode != 0:
-        emit("pencil_overlap_FAILED", 0.0, out.stderr.strip()[-120:])
-        return
-    for line in out.stdout.strip().splitlines():
-        parts = line.split(",")
-        emit(f"pencil_{parts[0]}", float(parts[1]) if parts[1] else 0.0,
-             parts[2] if len(parts) > 2 else "")
+    from repro.launch.mesh import make_mesh
+
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        # One device has no corner turn to overlap: nothing to measure.
+        raise RuntimeError(
+            f"pencil overlap needs at least 2 devices, found {n_dev}; on the "
+            "CPU set XLA_FLAGS=--xla_force_host_platform_device_count=8 "
+            "before JAX starts"
+        )
+    print(f"# Distributed pencil FFT: corner-turn overlap ({n_dev} "
+          f"{jax.devices()[0].platform} devices)")
+    mesh = make_mesh((n_dev,), ("data",))
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((SIZE, SIZE)).astype(np.float32)
+    xs = jax.device_put(jnp.asarray(x), pencil_sharding(mesh, "data", "rows"))
+
+    plain = jax.jit(lambda v: fft2_pencil(v, mesh, variant="stockham"))
+    over = jax.jit(
+        lambda v: fft2_pencil_overlapped(v, mesh, variant="stockham", chunks=4)
+    )
+    for name, fn in (("plain", plain), ("overlapped", over)):
+        jax.block_until_ready(fn(xs))
+        ts = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(xs))
+            ts.append(time.perf_counter() - t0)
+        hlo = fn.lower(xs).compile().as_text()
+        n_a2a = sum(1 for l in hlo.splitlines() if "all-to-all" in l and "=" in l)
+        emit(f"pencil_{name}", sorted(ts)[2] * 1e6, f"a2a_ops={n_a2a}")
+    ref = np.fft.fft2(x)
+    got = np.asarray(over(xs))
+    err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+    emit("pencil_overlap_rel_err", 0.0, f"{err:.2e}")
+    if not err <= REL_ERR_GATE:
+        raise RuntimeError(
+            f"overlapped pencil FFT off numpy by {err:.2e} (gate {REL_ERR_GATE})"
+        )
 
 
 if __name__ == "__main__":

@@ -18,6 +18,7 @@ import numpy as np
 
 import repro.xfft as xfft
 from repro.core import correlate2
+from repro.compile_cache import enable_compile_cache
 
 
 def make_scene(hw: int = 128, seed: int = 0):
@@ -35,6 +36,7 @@ def make_scene(hw: int = 128, seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     scene, template, true_pos = make_scene()
 
     # Real-input matched filter: rfft2 → conj-multiply → irfft2 (plan-backed

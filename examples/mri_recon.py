@@ -27,9 +27,11 @@ import repro.xfft as xfft
 from repro import mri
 from repro.plan import PlanCache
 from repro.serve import ImagingService, ReconRequest, wisdom
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=64, help="frame size (pow2)")
     ap.add_argument("--coils", type=int, default=4)

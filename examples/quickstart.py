@@ -14,9 +14,11 @@ import repro.xfft as xfft
 from repro.core import butterfly_counts
 from repro.core.fft2d import fft2_stream
 from repro.kernels import fft2_kernel, fft_kernel, hbm_traffic_model
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
 
     # 1. The paper's looped 1D engine (N/2 butterflies reused log2 N times),

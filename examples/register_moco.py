@@ -24,6 +24,7 @@ from repro.imaging import (
     kspace_to_image,
     register_phase_correlation,
 )
+from repro.compile_cache import enable_compile_cache
 
 
 def make_phantom(n: int = 128) -> np.ndarray:
@@ -41,6 +42,7 @@ def make_phantom(n: int = 128) -> np.ndarray:
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     n, frames = 128, 6
     phantom = make_phantom(n)

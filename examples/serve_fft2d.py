@@ -18,6 +18,7 @@ import numpy as np
 import repro.xfft as xfft
 from repro.core.fft2d import fft2_stream
 from repro.plan import default_cache, plan_fft
+from repro.compile_cache import enable_compile_cache
 
 
 def frame_source(step: int, batch: int, hw: int, seed: int = 0) -> np.ndarray:
@@ -30,6 +31,7 @@ def frame_source(step: int, batch: int, hw: int, seed: int = 0) -> np.ndarray:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=64, help="total frames to serve")
     ap.add_argument("--batch", type=int, default=8, help="frames per request")

@@ -29,9 +29,11 @@ from repro import obs
 from repro.plan import PlanCache
 from repro.resilience import ServicePolicy
 from repro.serve import BatchPolicy, SpectrumRequest, SpectrumService, wisdom
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=48)
     ap.add_argument("--hw", type=int, default=64)

@@ -20,9 +20,11 @@ from repro.configs.registry import get_config
 from repro.data.pipeline import make_batch
 from repro.models.build import build
 from repro.train.loop import TrainLoop
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--batch", type=int, default=8)

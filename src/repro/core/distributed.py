@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.fft1d import Variant, fft_impl
 
 __all__ = ["fft2_pencil", "fft2_pencil_overlapped", "pencil_sharding"]
@@ -65,7 +64,7 @@ def fft2_pencil(
     lead = (None,) * (ndim - 2)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(*lead, axis, None),
         out_specs=P(*lead, None, axis),
@@ -106,25 +105,28 @@ def fft2_pencil_overlapped(
             chunks = plan.chunks
     ndim = jnp.ndim(x)
     lead = (None,) * (ndim - 2)
-    h, w = x.shape[-2], x.shape[-1]
-    slab_w = w // chunks
+    w = x.shape[-1]
+    sub = w // (d * chunks)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(*lead, axis, None),
-        # (..., H, chunks, slab_w/d): slab index is a real axis so each slab's
-        # device-sharded columns stay contiguous in the global result.
-        out_specs=P(*lead, None, None, axis),
+        out_specs=P(*lead, None, axis),
     )
     def _run(block):
-        rows = fft_impl(block, axis=-1, variant=variant)
+        rows = fft_impl(block, axis=-1, variant=variant)   # (..., H/d, W)
+        h_loc = rows.shape[-2]
+        # Slab c holds the c-th ``sub``-wide piece of EVERY device's final
+        # column block, so each device's slabs concatenate into its own
+        # contiguous W/d columns: the result stays column-sharded instead
+        # of being gathered whole onto every device.
+        pieces = rows.reshape(*rows.shape[:-1], d, chunks, sub)
         outs = []
         for c in range(chunks):
-            slab = jax.lax.slice_in_dim(rows, c * slab_w, (c + 1) * slab_w, axis=-1)
-            turned = _corner_turn(slab, axis, d)          # (..., H, slab_w/d)
+            slab = pieces[..., c, :].reshape(*rows.shape[:-2], h_loc, d * sub)
+            turned = _corner_turn(slab, axis, d)          # (..., H, sub)
             outs.append(fft_impl(turned, axis=-2, variant=variant))
-        return jnp.stack(outs, axis=-2)                   # (..., H, chunks, slab_w/d)
+        return jnp.concatenate(outs, axis=-1)             # (..., H, W/d)
 
-    y = _run(x.astype(jnp.complex64))
-    return y.reshape(*x.shape[:-2], h, chunks * slab_w)
+    return _run(x.astype(jnp.complex64))

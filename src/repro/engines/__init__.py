@@ -89,7 +89,7 @@ def apply_engine(name: str, kind: str, x, *, direction: str = "fwd",
         return fn(arr)
 
     if spec.requires_x64:
-        from jax.experimental import enable_x64
+        from repro._x64 import enable_x64
 
         with enable_x64():
             return run()

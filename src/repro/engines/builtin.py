@@ -69,7 +69,10 @@ def _core_ops(name: str):
 
 def _fused_predicate(key) -> bool:
     """Fused kernels need power-of-two transform dims (and a real 2D frame
-    to actually be 2D)."""
+    to actually be 2D), and a backend that runs them: compiled on the TPU,
+    interpreted on the CPU."""
+    if key.backend not in ("tpu", "cpu"):
+        return False
     if key.kind in ("fft2d", "rfft2d"):
         if len(key.shape) < 2:
             return False
@@ -80,18 +83,19 @@ def _fused_predicate(key) -> bool:
 
 
 def _fused_working_set(key):
-    """Smallest VMEM residency the fused path needs: one 1D row tile of the
-    longest transform dim (the 2D kernels' unfused failover still runs the
-    1D kernel per pass, so a row tile must fit for ANY fused plan)."""
+    """Smallest VMEM residency the fused path needs: the smallest legal 1D
+    row tile of the longest transform dim (the 2D kernels' unfused failover
+    still runs the 1D kernel per pass, so a row tile must fit for ANY fused
+    plan)."""
     if key.kind in ("fft2d", "rfft2d"):
         if len(key.shape) < 2:
             return None
         dims = key.shape[-2:]
     else:
         dims = key.shape[-1:]
-    from repro.kernels.fft_radix2 import _FFT1_WORKING_ARRAYS  # lazy: pallas
+    from repro.kernels.fft_radix2 import fft1_working_set  # lazy: pallas
 
-    return max(dims) * 4 * _FFT1_WORKING_ARRAYS
+    return fft1_working_set(max(dims))
 
 
 def _register_builtin_engines() -> None:

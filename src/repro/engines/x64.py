@@ -24,6 +24,14 @@ from repro.engines.registry import CostHints, engine
 _KINDS = ("fft1d", "fft2d", "fft2d_stream", "rfft1d", "rfft2d")
 
 
+def _off_tpu(key) -> bool:
+    """XLA:TPU has no complex128 FFT (its compiler rejects the op with
+    ``Unexpected operand type for FFT: c128``), so the engine declines TPU
+    keys: a double request there fails in the planner, by name, instead of
+    inside dispatch."""
+    return key.backend != "tpu"
+
+
 @engine(
     "reference_x64",
     backend="x64",
@@ -31,6 +39,7 @@ _KINDS = ("fft1d", "fft2d", "fft2d_stream", "rfft1d", "rfft2d")
     precisions=("double",),
     dtypes=("complex128", "float64"),
     requires_x64=True,
+    predicate=_off_tpu,
     # The double ladder's always-works rung: jnp.fft under enable_x64,
     # immune to quarantine exhaustion like stockham is for single.
     reliable=True,
@@ -38,7 +47,7 @@ _KINDS = ("fft1d", "fft2d", "fft2d_stream", "rfft1d", "rfft2d")
 )
 def _reference_x64_ops(kind: str, direction: str):
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro._x64 import enable_x64
 
     inv = direction == "inv"
 

@@ -34,7 +34,7 @@ def butterfly_stage_kernel(re_ref, im_ref, out_re_ref, out_im_ref, *, stage: int
     h = re_ref.shape[-1]
     ar, br = re_ref[..., 0, :], re_ref[..., 1, :]
     ai, bi = im_ref[..., 0, :], im_ref[..., 1, :]
-    p = jax.lax.broadcasted_iota(jnp.float32, (1, 1, h), 2)
+    p = jax.lax.broadcasted_iota(jnp.int32, (1, 1, h), 2).astype(jnp.float32)
     ang = (-math.pi / h) * p  # -2π p / m, m = 2h
     wr, wi = jnp.cos(ang), jnp.sin(ang)
     tr = br * wr - bi * wi

@@ -2,7 +2,8 @@
 
 Complex in/out convenience wrappers around the (re, im) kernel ABI, with
 platform dispatch: real TPUs run the compiled kernels, CPU runs them in
-interpret mode (the kernel body executes in Python — bit-identical logic).
+interpret mode (the kernel body executes in Python — bit-identical logic),
+and any other backend is refused.
 
   fft_kernel(x)    — fused 1D FFT (one HBM round trip)       [proposed]
   fft_staged(x)    — stage-at-a-time via the BU-array kernel [column-arch baseline]
@@ -90,7 +91,15 @@ def fft2_fits_budget(h: int, w: int, *, real: bool = False) -> bool:
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpreted on the CPU (the tests); no other
+    backend may quietly interpret the kernels."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the fused FFT kernels run compiled on 'tpu' or interpreted on "
+            f"'cpu'; backend {backend!r} is neither"
+        )
+    return backend == "cpu"
 
 
 def _failover_event(kind: str, h: int, w: int, frames: int, *, real: bool) -> None:

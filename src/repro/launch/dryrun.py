@@ -1,5 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+if __name__ == "__main__":
+    # 512 fake host devices for the production meshes; set before JAX
+    # starts, and only when run as the dry-run script — importing this
+    # module leaves the process's devices alone.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture × input-shape × mesh) cell against the production meshes,
@@ -16,7 +21,6 @@ import time  # noqa: E402
 import traceback  # noqa: E402
 
 import jax  # noqa: E402
-from repro import compat
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
@@ -31,7 +35,7 @@ from repro.configs.registry import (  # noqa: E402
 from repro.launch.hlo_analysis import collective_schedule, collective_stats  # noqa: E402
 from repro.launch.hlo_cost import loop_aware_cost, top_collectives  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.roofline import Roofline, model_flops  # noqa: E402
+from repro.launch.roofline import Roofline, chip_peaks, model_flops  # noqa: E402
 from repro.models.build import build  # noqa: E402
 from repro.optim import adamw_init  # noqa: E402
 from repro.sharding import batch_specs, cache_specs, param_rules  # noqa: E402
@@ -154,7 +158,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, overrides=None,
     step, args, shardings, meta = build_cell(
         arch, shape, mesh, multi_pod, overrides, bf16_params=bf16_params
     )
-    with compat.set_mesh(mesh), activation_sharding(
+    with jax.set_mesh(mesh), activation_sharding(
         dp=dp, dp_sizes=dp_sizes, tp=tp, tp_size=16, cp=cp, cp_size=16,
     ):
         lowered = jax.jit(step, in_shardings=shardings).lower(*args)
@@ -178,6 +182,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, overrides=None,
         collective_bytes_per_device=float(lac["collective_traffic_bytes"]),
         n_devices=n_dev,
         model_flops_global=mf,
+        peaks=chip_peaks("TPU v5 lite"),  # the dry-run's target pod
     )
     return {
         "arch": arch,

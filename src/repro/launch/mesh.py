@@ -5,7 +5,16 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init)."""
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes (jax defaults to Explicit), so
+    sharding constraints and ``jax.shard_map`` partial-manual axes behave
+    the way the models and the pencil FFT were written for."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

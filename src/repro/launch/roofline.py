@@ -1,4 +1,4 @@
-"""Roofline terms from the compiled dry-run artifact (TPU v5e targets).
+"""Roofline terms from the compiled dry-run artifact, against per-chip peaks.
 
   compute    = HLO_FLOPs_per_device / peak_FLOP/s
   memory     = HLO_bytes_per_device / HBM_bw
@@ -13,9 +13,41 @@ import dataclasses
 
 import numpy as np
 
-PEAK_FLOPS_BF16 = 197e12     # per v5e chip
-HBM_BW = 819e9               # B/s per chip
-ICI_LINK_BW = 50e9           # B/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks, with where they were published."""
+
+    flops_bf16: float    # FLOP/s
+    hbm_bw: float        # B/s
+    ici_link_bw: float   # B/s per interconnect link
+    source: str
+
+
+#: Peaks keyed by ``jax.Device.device_kind``. A kind missing here is an
+#: error (:func:`chip_peaks`), never a default.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bw=819e9,
+        ici_link_bw=50e9,
+        source=(
+            "Google Cloud TPU docs, 'TPU v5e': 197 TFLOP/s bf16, 16 GB HBM "
+            "at 819 GB/s, 1,600 Gbit/s ICI over 4 links"
+        ),
+    ),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table entry for ``device_kind``; raises for unknown kinds."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"add it to repro.launch.roofline.PEAKS (known: {tuple(PEAKS)})"
+        ) from None
 
 
 @dataclasses.dataclass
@@ -25,18 +57,19 @@ class Roofline:
     collective_bytes_per_device: float
     n_devices: int
     model_flops_global: float
+    peaks: ChipPeaks
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / ICI_LINK_BW
+        return self.collective_bytes_per_device / self.peaks.ici_link_bw
 
     @property
     def dominant(self) -> str:
@@ -63,7 +96,7 @@ class Roofline:
         t = self.step_time_s
         if t == 0:
             return 0.0
-        return self.model_flops_global / (t * self.n_devices * PEAK_FLOPS_BF16)
+        return self.model_flops_global / (t * self.n_devices * self.peaks.flops_bf16)
 
     def to_dict(self) -> dict:
         return {

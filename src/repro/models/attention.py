@@ -13,7 +13,6 @@ import math
 from typing import Any
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 
 from repro.models.config import MLAConfig, ModelConfig
@@ -98,7 +97,7 @@ def flash_attention(
         l0 = jnp.zeros((b, block_q, kv, g), jnp.float32)
         if vma_axes:  # inside shard_map: mark carries as manual-varying
             acc0, m0, l0 = (
-                compat.pcast(t, vma_axes, to="varying") for t in (acc0, m0, l0)
+                jax.lax.pcast(t, vma_axes, to="varying") for t in (acc0, m0, l0)
             )
         (acc, _, l), _ = jax.lax.scan(
             jax.checkpoint(kv_step), (acc0, m0, l0), jnp.arange(nk)
@@ -126,10 +125,10 @@ def flash_attention_cp(
 
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
 
     @_ft.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis, None, None), P(), P()),
         out_specs=P(None, axis, None, None),

@@ -19,7 +19,6 @@ import functools
 from typing import Any
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig, MoEConfig
@@ -180,7 +179,7 @@ def _moe_ep_a2a(p, x, m: MoEConfig, act: str, ep_axis):
     combine. Two activation-sized collectives instead of per-layer weight
     gathering — the collective-term optimization for the MoE cells.
     """
-    axis_size = compat.axis_size(ep_axis)
+    axis_size = jax.lax.axis_size(ep_axis)
     e_loc = m.n_experts // axis_size
     b, s, d = x.shape  # local shapes inside shard_map
     gates, ids, aux = _router(p, x, m)
@@ -248,13 +247,13 @@ def _moe_ep_shard_map(p, x, m: MoEConfig, act: str, ep_axes: tuple):
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axis_name = ep_axes if len(ep_axes) > 1 else ep_axes[0]
     manual = set(ep_axes)
     auto = frozenset(a for a in mesh.axis_names if a not in manual)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             {
@@ -288,7 +287,7 @@ def moe_apply(
     impl = m.impl
     ep_axes = tuple(ep_axis) if ep_axis else tuple(m.ep_axes)
     if impl == "ep_a2a":
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if not ep_axes or mesh.empty or any(a not in mesh.axis_names for a in ep_axes):
             impl = "grouped_local"  # no mesh context (CPU smoke tests)
     if impl == "dense_small":

@@ -69,9 +69,24 @@ _FLOPS_PER_BUTTERFLY = 10.0
 _KERNEL_LAUNCH_S = 2.0e-6
 _INTERPRET_OVERHEAD_S = 20.0e-6
 
-# CPU backends sit far off the TPU roofline constants; only the *ranking*
-# matters for planning, but scaling keeps est_time_s roughly honest.
+# CPU backends sit far off any chip's roofline; only the *ranking* matters
+# for planning there, but scaling keeps est_time_s roughly honest.
 _BACKEND_SLOWDOWN = {"cpu": 40.0}
+
+#: The peak-table entry non-TPU backends rank against (then scaled by
+#: ``_BACKEND_SLOWDOWN``); TPU keys use their own ``device_kind``'s entry.
+_RANKING_DEVICE_KIND = "TPU v5 lite"
+
+
+def _roofline_peaks(key: ProblemKey):
+    """Peaks ESTIMATE prices ``key`` against: the chip's own entry in
+    :data:`repro.launch.roofline.PEAKS` on TPU (an unknown kind raises),
+    a ranking-only scale on every other backend."""
+    from repro.launch.roofline import chip_peaks
+
+    if key.backend == "tpu":
+        return chip_peaks(key.device_kind)
+    return chip_peaks(_RANKING_DEVICE_KIND)
 
 #: Real-input (two-for-one) kinds.
 _REAL_KINDS = ("rfft1d", "rfft2d")
@@ -109,8 +124,8 @@ def variant_candidates(key: ProblemKey) -> Tuple[str, ...]:
         scope = f" under backend scope {key.backends}" if key.backends else ""
         raise ValueError(
             f"no registered engine supports kind {key.kind!r} at precision "
-            f"{key.precision!r}{scope}; registered engines: "
-            f"{tuple(s.name for s in iter_engines())}"
+            f"{key.precision!r}{scope} on backend {key.backend!r}; "
+            f"registered engines: {tuple(s.name for s in iter_engines())}"
         )
     breaker = quarantine()
     healthy = tuple(
@@ -203,6 +218,7 @@ def estimate_variant_time(key: ProblemKey, variant: str) -> float:
         collective_bytes_per_device=collective,
         n_devices=key.n_devices,
         model_flops_global=flops,
+        peaks=_roofline_peaks(key),
     )
     t = rl.step_time_s * _BACKEND_SLOWDOWN.get(key.backend, 1.0)
     if spec.fused:
@@ -245,10 +261,8 @@ def _estimate_chunks(key: ProblemKey) -> int:
         ),
         "stockham",
     )
-    from repro.launch.roofline import ICI_LINK_BW
-
     collective_s = 8.0 * float(np.prod(key.shape, dtype=np.int64)) / (
-        key.n_devices * ICI_LINK_BW
+        key.n_devices * _roofline_peaks(key).ici_link_bw
     )
     ideal = max(1.0, collective_s / max(compute_s, 1e-12))
     # Closest legal slab count to the overlap ideal; ties favour more slabs.
@@ -488,7 +502,7 @@ def measure_plan(
     if budget_s is None:
         budget_s = MEASURE_CANDIDATE_BUDGET_S
     if key.precision == "double":
-        from jax.experimental import enable_x64  # lazy
+        from repro._x64 import enable_x64  # lazy
 
         with enable_x64():
             return _measure_plan_impl(key, warmup, iters, timings_out, budget_s)

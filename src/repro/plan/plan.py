@@ -94,7 +94,7 @@ class ProblemKey:
 
     kind: str                  # one of KINDS
     backend: str               # jax.default_backend(): "cpu" | "gpu" | "tpu"
-    device_kind: str           # e.g. "TPU v5e", "cpu"
+    device_kind: str           # jax device_kind, e.g. "TPU v5 lite", "cpu"
     shape: Tuple[int, ...]
     dtype: str                 # canonical dtype name, e.g. "complex64"
     n_devices: int = 1

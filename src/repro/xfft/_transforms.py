@@ -34,7 +34,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64 as _enable_x64
+from repro._x64 import enable_x64 as _enable_x64
 
 from repro.core.fft1d import _check_pow2 as _core_check_pow2
 from repro.core.fft1d import canonical_axis

@@ -14,7 +14,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import tempfile
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.checkpoint import restore_resharded, save
 from repro.configs.registry import smoke_config
 from repro.models.build import build

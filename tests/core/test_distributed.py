@@ -11,7 +11,7 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import fft2_pencil, fft2_pencil_overlapped, pencil_sharding
 
 mesh = make_mesh((8,), ("data",))

@@ -39,7 +39,8 @@ def test_radix4_fused_matches_jnp_fft(rng, n):
     assert np.max(np.abs(r4 - r2)) / scale <= 1e-4
 
 
-@pytest.mark.parametrize("n", [2, 4, 16, 32, 128, 512])
+# 1 << 15 is past the turned-block census: the four-step (N/128, 128) layout.
+@pytest.mark.parametrize("n", [2, 4, 16, 32, 128, 512, 1 << 15])
 def test_radix4_fused_all_parities(rng, n):
     """Odd log2(N) falls back to one radix-2 stage; every size stays exact."""
     x = (rng.standard_normal((2, n)) + 1j * rng.standard_normal((2, n))).astype(
@@ -61,7 +62,8 @@ def test_fused_2d_kernel_radix(rng, hw, radix):
     np.testing.assert_allclose(got / scale, ref / scale, atol=1e-5)
 
 
-@pytest.mark.parametrize("n", [2, 8, 64, 1024])
+# 4096 and 1 << 15 take the four-step layout (complex kernel at full length).
+@pytest.mark.parametrize("n", [2, 8, 64, 1024, 4096, 1 << 15])
 @pytest.mark.parametrize("radix", [2, 4])
 def test_rfft_kernel_matches_numpy(rng, n, radix):
     x = rng.standard_normal((3, n)).astype(np.float32)

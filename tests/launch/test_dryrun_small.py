@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.registry import smoke_config, SHAPES
 from repro.launch.hlo_cost import loop_aware_cost
 from repro.launch.mesh import make_test_mesh
@@ -52,7 +51,7 @@ for arch in ARCHS:
     step = make_train_step(model.loss_fn)
     named = lambda t: jax.tree.map(lambda sp: NamedSharding(mesh, sp), t,
                                    is_leaf=lambda x: isinstance(x, P))
-    with compat.set_mesh(mesh), activation_sharding(
+    with jax.set_mesh(mesh), activation_sharding(
         dp=("data",), dp_sizes=(4,), tp="model", tp_size=2
     ):
         compiled = jax.jit(

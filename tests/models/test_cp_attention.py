@@ -4,23 +4,22 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.models.attention import flash_attention, flash_attention_cp
 
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rng = np.random.default_rng(0)
 B, S, H, KV, D = 4, 64, 6, 2, 16
 q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
 k = jnp.asarray(rng.standard_normal((B, S, KV, D)), jnp.float32)
 v = jnp.asarray(rng.standard_normal((B, S, KV, D)), jnp.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for kw in ({"causal": True}, {"causal": True, "window": 24},
                {"causal": False}):
         ref = flash_attention(q, k, v, block_q=16, block_k=16, **kw)
@@ -39,10 +38,6 @@ print("CP_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (pcast/varying axes) needs jax>=0.5; 0.4.x XLA partitioner aborts",
-)
 def test_cp_attention_matches_plain():
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run(

@@ -5,7 +5,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 _SCRIPT = r"""
@@ -13,12 +12,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.param import init_params
 from repro.models.moe import moe_skel, moe_apply
 
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg_g = ModelConfig(name="t", family="moe", n_layers=1, d_model=32, n_heads=4,
                     n_kv_heads=4, d_ff=64, vocab=100,
                     moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=32,
@@ -32,7 +31,7 @@ x = jnp.asarray(rng.standard_normal((8, 16, 32)), jnp.float32)
 for ep_axes in (("data",), ("data", "model")):
     cfg_e = dataclasses.replace(cfg_g, moe=dataclasses.replace(
         cfg_g.moe, impl="ep_a2a", ep_axes=ep_axes))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         yg, _ = jax.jit(lambda p, x: moe_apply(p, x, cfg_g))(p, x)
         ye, _ = jax.jit(lambda p, x: moe_apply(p, x, cfg_e))(p, x)
         err = float(jnp.max(jnp.abs(yg - ye)))
@@ -53,10 +52,6 @@ print("MOE_EP_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (auto axes) needs jax>=0.5; 0.4.x XLA partitioner aborts",
-)
 def test_ep_a2a_matches_grouped_local():
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run(

@@ -5,7 +5,7 @@ double precision."""
 
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from repro._x64 import enable_x64
 
 import repro.xfft as xfft
 from repro import mri

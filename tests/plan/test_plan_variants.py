@@ -143,7 +143,7 @@ def test_estimate_prefers_fused_on_tpu_keys():
     on CPU (interpret mode) they don't get the HBM credit."""
     from repro.plan import ProblemKey, estimate_plan
 
-    tpu = ProblemKey(kind="fft2d", backend="tpu", device_kind="TPU v5e",
+    tpu = ProblemKey(kind="fft2d", backend="tpu", device_kind="TPU v5 lite",
                      shape=(1024, 1024), dtype="complex64")
     cpu = ProblemKey(kind="fft2d", backend="cpu", device_kind="cpu",
                      shape=(1024, 1024), dtype="complex64")
