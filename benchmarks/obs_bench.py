@@ -25,9 +25,8 @@ Four gates, all about trusting the ``repro.obs`` layer:
    kinds, the planner calibration ledger must hold >= 3 (engine, kind)
    rows with observed dispatch samples and an observed/predicted ratio.
 
-Also writes CI-artifact snapshots: a Chrome-trace/Perfetto JSON of the
-flight recorder's window (``--trace-out``) and a Prometheus text
-exposition of counters + latency histograms (``--prom-out``).
+Also writes a CI-artifact snapshot: a Prometheus text exposition of
+counters + latency histograms (``--prom-out``).
 
   PYTHONPATH=src python benchmarks/obs_bench.py --size 256
   PYTHONPATH=src python -m benchmarks.run obs
@@ -49,7 +48,7 @@ import numpy as np
 import repro.xfft as xfft
 from repro import obs, resilience
 from repro.obs import telemetry
-from repro.obs.export import write_chrome_trace, write_prometheus
+from repro.obs.export import write_prometheus
 from repro.plan import PlanCache, reset_default_cache
 from repro.plan.api import resolve_call
 from repro.resilience import FaultPlan, FaultSpec
@@ -220,13 +219,10 @@ def bench_calibration(n: int, iters: int) -> dict:
     }
 
 
-def export_snapshots(trace_out: str, prom_out: str) -> dict:
-    """Write the CI-artifact views: Chrome trace of the flight recorder's
-    retained window, Prometheus exposition of counters + histograms."""
+def export_snapshots(prom_out: str) -> dict:
+    """Write the CI-artifact view: Prometheus exposition of counters +
+    histograms, with the flight recorder's retention as gauges."""
     rec = obs.flight_recorder()
-    events = rec.events() if rec is not None else []
-    names = rec.thread_names() if rec is not None else {}
-    write_chrome_trace(events, trace_out, thread_names=names)
     gauges = {}
     if rec is not None:
         stats = rec.stats()
@@ -241,8 +237,6 @@ def export_snapshots(trace_out: str, prom_out: str) -> dict:
         histograms=obs.histograms(),
     )
     return {
-        "chrome_trace": trace_out,
-        "chrome_trace_events": len(events),
         "prometheus": prom_out,
         "histograms_exported": len(obs.histograms()),
     }
@@ -266,8 +260,6 @@ def main(argv=None):
     ap.add_argument("--gate-pct", type=float, default=3.0,
                     help="max tolerated instrumentation overhead, percent")
     ap.add_argument("--out", default=None, help="write the JSON report here")
-    ap.add_argument("--trace-out", default="/tmp/obs_trace.json",
-                    help="write the Chrome-trace snapshot here")
     ap.add_argument("--prom-out", default="/tmp/obs_metrics.prom",
                     help="write the Prometheus exposition here")
     args = ap.parse_args(argv)
@@ -288,7 +280,7 @@ def main(argv=None):
     with tempfile.TemporaryDirectory() as dump_dir:
         flight = bench_flight_dump(args.measure_size, dump_dir)
     calibration = bench_calibration(args.measure_size, iters=5)
-    snapshots = export_snapshots(args.trace_out, args.prom_out)
+    snapshots = export_snapshots(args.prom_out)
     report = {
         "backend": jax.default_backend(),
         "gate_pct": args.gate_pct,

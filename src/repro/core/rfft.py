@@ -19,6 +19,9 @@ half-size panel + recombination in one VMEM residency — and ``"auto"``,
 planned through ``repro.plan`` under the ``rfft1d``/``rfft2d`` problem
 kinds). The public names are deprecated aliases of the ``repro.xfft``
 front door, which adds ``norm=`` conventions and plan-backed dispatch.
+
+The jnp 2D paths dispatch their row and column passes separately, each
+under a ``repro.obs`` span (``fft.rows``, ``fft.columns``).
 """
 
 from __future__ import annotations
@@ -28,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core._deprecation import warn_deprecated
 from repro.core.fft1d import (
     BUILTIN_VARIANTS,
@@ -173,8 +177,10 @@ def rfft2_impl(x: jax.Array, variant: Variant = "auto") -> jax.Array:
         from repro.kernels.ops import rfft2_kernel  # lazy: kernels import core
 
         return rfft2_kernel(x, radix=_radix(variant))
-    y = rfft_impl(x, axis=-1, variant=variant)
-    return fft_impl(y, axis=-2, variant=variant)
+    with obs.span("fft.rows"):
+        y = rfft_impl(x, axis=-1, variant=variant)
+    with obs.span("fft.columns"):
+        return fft_impl(y, axis=-2, variant=variant)
 
 
 def irfft2_impl(y: jax.Array, variant: Variant = "auto") -> jax.Array:
@@ -193,8 +199,10 @@ def irfft2_impl(y: jax.Array, variant: Variant = "auto") -> jax.Array:
         from repro.kernels.ops import irfft2_kernel  # lazy: kernels import core
 
         return irfft2_kernel(y, radix=_radix(variant))
-    z = ifft_impl(y, axis=-2, variant=variant)
-    return irfft_impl(z, axis=-1, variant=variant)
+    with obs.span("fft.columns"):
+        z = ifft_impl(y, axis=-2, variant=variant)
+    with obs.span("fft.rows"):
+        return irfft_impl(z, axis=-1, variant=variant)
 
 
 # --------------------- deprecated public entry points ---------------------

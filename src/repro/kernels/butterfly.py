@@ -92,5 +92,6 @@ def butterfly_stage(
             jax.ShapeDtypeStruct(im4.shape, jnp.float32),
         ],
         interpret=interpret,
+        name="repro_butterfly_stage",
     )(re4.astype(jnp.float32), im4.astype(jnp.float32))
     return out_re.reshape(b, n), out_im.reshape(b, n)

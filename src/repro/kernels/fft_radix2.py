@@ -392,6 +392,7 @@ def _fft_split(re, im, *, tile: int, radix: int, interpret: bool):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_fft_fused_split",
     )(re.reshape(bp, n1, _LANES), im.reshape(bp, n1, _LANES))
     return yr.reshape(bp, n), yi.reshape(bp, n)
 
@@ -431,6 +432,7 @@ def fft_fused(
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_fft_fused",
     )(re, im)
     return yr[:b], yi[:b]
 
@@ -480,6 +482,7 @@ def rfft_fused(
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_rfft_fused",
     )(x)
     return yr[:b], yi[:b]
 
@@ -527,6 +530,7 @@ def irfft_fused(
         out_shape=jax.ShapeDtypeStruct((bp, n), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_irfft_fused",
     )(re, im)
     return out[:b]
 
@@ -579,6 +583,7 @@ def fft2_fused(
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_fft2_fused",
     )(re.astype(jnp.float32), im.astype(jnp.float32))
 
 
@@ -613,6 +618,7 @@ def rfft2_fused(x: jax.Array, *, radix: int = 2, interpret: bool = False):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_rfft2_fused",
     )(x.astype(jnp.float32))
 
 
@@ -644,4 +650,5 @@ def irfft2_fused(re: jax.Array, im: jax.Array, *, radix: int = 2, interpret: boo
         out_shape=jax.ShapeDtypeStruct((f, h, w), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="repro_irfft2_fused",
     )(re.astype(jnp.float32), im.astype(jnp.float32))

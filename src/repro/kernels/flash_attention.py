@@ -124,6 +124,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="repro_flash_attention_fwd",
     )(q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32))
     return out[:, :sq]
 

@@ -15,6 +15,11 @@ All fused entry points take ``radix`` (2 or 4): radix-4 halves the in-VMEM
 stage count and the twiddle transcendentals. 2D entry points fail over to an
 unfused row/column composition when the frame's true working set exceeds the
 VMEM budget (``fft2_fits_vmem``) instead of overflowing it.
+
+``fft2_kernel`` and ``rfft2_kernel`` dispatch their stages separately, each
+under a ``repro.obs`` span: ``kernel.launch`` (the fused Pallas call) and
+``kernel.assemble`` (the complex result from the kernel's re/im planes), or
+on the failover ``fft.rows`` and ``fft.columns``.
 """
 
 from __future__ import annotations
@@ -192,19 +197,23 @@ def fft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) 
     if fft2_fits_vmem(h, w) and not _faults.vmem_exhausted(
         "kernel.fused", kind="fft2d", h=h, w=w
     ):
-        yr, yi = fft2_fused(re, im, radix=radix, interpret=interpret)
+        with obs.span("kernel.launch", kernel="repro_fft2_fused"):
+            yr, yi = fft2_fused(re, im, radix=radix, interpret=interpret)
     else:
         # Frame working set exceeds VMEM: row pass, materialised corner
         # turn, column pass — more HBM trips, but never an overflow.
         _failover_event("fft2d", h, w, f, real=False)
-        yr, yi = _fft_rows(re.reshape(f * h, w), im.reshape(f * h, w),
-                           radix=radix, interpret=interpret)
-        yr = yr.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
-        yi = yi.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
-        yr, yi = _fft_rows(yr, yi, radix=radix, interpret=interpret)
-        yr = yr.reshape(f, w, h).swapaxes(-1, -2)
-        yi = yi.reshape(f, w, h).swapaxes(-1, -2)
-    return (yr + 1j * yi).reshape(*lead, h, w)
+        with obs.span("fft.rows"):
+            yr, yi = _fft_rows(re.reshape(f * h, w), im.reshape(f * h, w),
+                               radix=radix, interpret=interpret)
+        with obs.span("fft.columns"):
+            yr = yr.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
+            yi = yi.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
+            yr, yi = _fft_rows(yr, yi, radix=radix, interpret=interpret)
+            yr = yr.reshape(f, w, h).swapaxes(-1, -2)
+            yi = yi.reshape(f, w, h).swapaxes(-1, -2)
+    with obs.span("kernel.assemble"):
+        return (yr + 1j * yi).reshape(*lead, h, w)
 
 
 def rfft_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
@@ -235,7 +244,8 @@ def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None)
     if fft2_fits_vmem(h, w, arrays=_REAL2D_ARRAYS) and not _faults.vmem_exhausted(
         "kernel.fused", kind="rfft2d", h=h, w=w
     ):
-        yr, yi = rfft2_fused(xf, radix=radix, interpret=interpret)
+        with obs.span("kernel.launch", kernel="repro_rfft2_fused"):
+            yr, yi = rfft2_fused(xf, radix=radix, interpret=interpret)
     else:
         # Unfused failover: row rfft kernel, corner turn in HBM, column FFT.
         # The column batch (f·(W/2+1) rows) is odd, which would force the
@@ -245,18 +255,22 @@ def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None)
         from repro.core.fft1d import fft_impl  # lazy: core imports kernels
 
         half = w // 2 + 1
-        if fft_fits_vmem(w):
-            yr, yi = rfft_fused(xf.reshape(f * h, w), radix=radix, interpret=interpret)
-            z = (yr + 1j * yi).reshape(f, h, half)
-        else:
-            from repro.core.rfft import rfft_impl  # rows too long for any tile
+        with obs.span("fft.rows"):
+            if fft_fits_vmem(w):
+                yr, yi = rfft_fused(xf.reshape(f * h, w), radix=radix,
+                                    interpret=interpret)
+                z = (yr + 1j * yi).reshape(f, h, half)
+            else:
+                from repro.core.rfft import rfft_impl  # rows too long for any tile
 
-            z = rfft_impl(xf.reshape(f * h, w), variant=_jnp_variant(radix))
-            z = z.reshape(f, h, half)
-        z = fft_impl(z.swapaxes(-1, -2), variant=_jnp_variant(radix))
-        z = z.swapaxes(-1, -2)
-        return z.reshape(*lead, h, half)
-    return (yr + 1j * yi).reshape(*lead, h, w // 2 + 1)
+                z = rfft_impl(xf.reshape(f * h, w), variant=_jnp_variant(radix))
+                z = z.reshape(f, h, half)
+        with obs.span("fft.columns"):
+            z = fft_impl(z.swapaxes(-1, -2), variant=_jnp_variant(radix))
+            z = z.swapaxes(-1, -2)
+            return z.reshape(*lead, h, half)
+    with obs.span("kernel.assemble"):
+        return (yr + 1j * yi).reshape(*lead, h, w // 2 + 1)
 
 
 def irfft2_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:

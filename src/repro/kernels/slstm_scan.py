@@ -122,6 +122,7 @@ def slstm_scan(xg, wr, bias, c0, n0, h0, m0, *, chunk: int = 256,
             pltpu.VMEM((b, d), jnp.float32),
         ],
         interpret=interpret,
+        name="repro_slstm_scan",
     )(
         xg.astype(jnp.float32), wr.astype(jnp.float32), bias.astype(jnp.float32),
         c0.astype(jnp.float32), n0.astype(jnp.float32),

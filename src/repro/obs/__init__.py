@@ -28,9 +28,16 @@ installed at import): a bounded **flight recorder** keeps the most
 recent events with no capture scope open and dumps a JSONL snapshot
 when a failure trigger fires, and a **calibration ledger** joins planner
 predictions against observed engine dispatch times. Latency histograms
-(:mod:`repro.obs.hist`) and exporters — JSONL, Chrome trace, Prometheus
-text (:mod:`repro.obs.export`) — make all of it consumable by standard
+(:mod:`repro.obs.hist`) and exporters — JSONL and Prometheus text
+(:mod:`repro.obs.export`) — make all of it consumable by standard
 tooling.
+
+Under profiling (``xfft.config(observe=True)`` or
+``capture(profile=True)``) each span also becomes a
+``jax.profiler.TraceAnnotation`` carrying its ``span_id`` and
+``call_id``, and a :class:`SpanRecord` in a bounded buffer read by
+:func:`spans`: the program's own host timeline, joinable to the
+profiler's device trace.
 """
 
 from repro.obs import export, hist, telemetry
@@ -42,6 +49,7 @@ from repro.obs.hist import (
 )
 from repro.obs.record import (
     Event,
+    SpanRecord,
     Trace,
     add_sink,
     capture,
@@ -54,7 +62,9 @@ from repro.obs.record import (
     push_observe,
     remove_sink,
     reset_counters,
+    reset_spans,
     span,
+    spans,
 )
 from repro.obs.telemetry import (
     CalibrationLedger,
@@ -69,6 +79,7 @@ __all__ = [
     "Event",
     "FlightRecorder",
     "LatencyHistogram",
+    "SpanRecord",
     "Trace",
     "add_sink",
     "calibration_ledger",
@@ -88,8 +99,10 @@ __all__ = [
     "remove_sink",
     "reset_counters",
     "reset_histograms",
+    "reset_spans",
     "set_flight_recorder",
     "span",
+    "spans",
     "telemetry",
 ]
 

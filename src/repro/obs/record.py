@@ -24,6 +24,15 @@ Three primitives, one cost rule:
   (a serving fleet member) still answers "did my shipped wisdom load?"
   through :func:`repro.xfft.report`.
 
+While profiling is scoped on, every span also leaves a
+:class:`SpanRecord` in a bounded process-wide buffer (:func:`spans`): its
+id, the id of the span open around it, the id of the outermost span of
+its call, ``perf_counter_ns`` start and end, and its thread. The span's
+``TraceAnnotation`` carries the same ``span_id`` and ``call_id`` as
+metadata, so the records join the profiler's device trace: the offset
+between the two clocks comes from any span present in both. With
+profiling off a span allocates no id and keeps no record.
+
 Scoping is :mod:`contextvars`-based: capture scopes nest (an inner scope
 sees only its own window; every enclosing scope sees the inner events
 too), compose across async tasks, and never observe another thread's
@@ -44,6 +53,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
@@ -60,7 +70,11 @@ __all__ = [
     "profiling",
     "remove_sink",
     "reset_counters",
+    "reset_spans",
     "span",
+    "SpanRecord",
+    "spans",
+    "SPAN_CAPACITY",
 ]
 
 
@@ -129,6 +143,29 @@ def _short(v: Any) -> str:
     return s if len(s) <= 48 else s[:45] + "..."
 
 
+@dataclasses.dataclass(frozen=True)
+class SpanRecord:
+    """One span closed while profiling was on.
+
+    ``call_id`` is the ``span_id`` of the outermost span open when this
+    one started (a root span's ``call_id`` is its own id). Times are
+    ``time.perf_counter_ns()``; ``tid`` is ``threading.get_ident()``.
+    """
+
+    name: str
+    span_id: int
+    parent_id: Optional[int]
+    call_id: int
+    start_ns: int
+    end_ns: int
+    tid: int
+    fields: Dict[str, Any]
+
+    @property
+    def duration_us(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e3
+
+
 # ------------------------------ collectors --------------------------------
 
 _STACK: contextvars.ContextVar[Tuple[Trace, ...]] = contextvars.ContextVar(
@@ -138,8 +175,20 @@ _PROFILE: contextvars.ContextVar[bool] = contextvars.ContextVar(
     "repro_obs_profile", default=False
 )
 
+#: (span_id, call_id) of the profiled span open in this context, if any.
+_OPEN_SPAN: contextvars.ContextVar[Optional[Tuple[int, int]]] = contextvars.ContextVar(
+    "repro_obs_open_span", default=None
+)
+
 _COUNTS: Dict[str, int] = {}
 _COUNTS_LOCK = threading.Lock()
+
+#: Records kept at most; a record past it is dropped and counted as
+#: ``obs.spans.dropped``.
+SPAN_CAPACITY = 1 << 16
+_SPAN_IDS = itertools.count(1)
+_SPANS: List[SpanRecord] = []
+_SPANS_LOCK = threading.Lock()
 
 # Process-wide sinks: callables fed every Event from every thread. Stored
 # as an immutable tuple so emit() reads one reference with no lock; the
@@ -194,6 +243,27 @@ def reset_counters() -> None:
         _COUNTS.clear()
 
 
+def spans() -> List[SpanRecord]:
+    """Snapshot of the span records kept while profiling was on, in the
+    order the spans closed."""
+    with _SPANS_LOCK:
+        return list(_SPANS)
+
+
+def reset_spans() -> None:
+    """Empty the span-record buffer."""
+    with _SPANS_LOCK:
+        _SPANS.clear()
+
+
+def _keep(record: SpanRecord) -> None:
+    with _SPANS_LOCK:
+        if len(_SPANS) < SPAN_CAPACITY:
+            _SPANS.append(record)
+            return
+    count("obs.spans.dropped")
+
+
 def emit(name: str, **fields: Any) -> Optional[Event]:
     """Record one event; returns it when any capture scope received it.
 
@@ -224,14 +294,15 @@ def emit(name: str, **fields: Any) -> Optional[Event]:
     return event if stack else None
 
 
-def _annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None when jax
-    (or the annotation API) is unavailable — profiling degrades silently
-    rather than making obs depend on jax."""
+def _annotation(name: str, **metadata: Any):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` carrying
+    ``metadata`` as event stats (the event keeps the bare name), or None
+    when jax (or the annotation API) is unavailable — profiling degrades
+    silently rather than making obs depend on jax."""
     try:  # pragma: no cover - depends on installed jax
         from jax.profiler import TraceAnnotation
 
-        return TraceAnnotation(name)
+        return TraceAnnotation(name, **metadata)
     except Exception:  # pragma: no cover
         return None
 
@@ -247,7 +318,9 @@ def span(name: str, **fields: Any):
             out["chosen"] = sweep()
 
     When profiling is scoped on, the region is also wrapped in a
-    ``jax.profiler.TraceAnnotation`` so it shows up in XLA traces.
+    ``jax.profiler.TraceAnnotation`` (with ``span_id`` and ``call_id``
+    metadata) so it shows up in XLA traces, and its :class:`SpanRecord`
+    is kept for :func:`spans`.
     """
     extra: Dict[str, Any] = {}
     stack = _STACK.get()
@@ -257,17 +330,28 @@ def span(name: str, **fields: Any):
         count(name)
         yield extra
         return
-    annotation = _annotation(name) if prof else None
-    if annotation is not None:
-        annotation.__enter__()
-    t0 = time.perf_counter()
+    annotation = token = None
+    if prof:
+        span_id = next(_SPAN_IDS)
+        outer = _OPEN_SPAN.get()
+        parent_id, call_id = (None, span_id) if outer is None else outer
+        token = _OPEN_SPAN.set((span_id, call_id))
+        annotation = _annotation(name, span_id=span_id, call_id=call_id)
+        if annotation is not None:
+            annotation.__enter__()
+    t0 = time.perf_counter_ns()
     try:
         yield extra
     finally:
-        duration_us = (time.perf_counter() - t0) * 1e6
+        t1 = time.perf_counter_ns()
         if annotation is not None:
             annotation.__exit__(None, None, None)
-        emit(name, duration_us=duration_us, **{**fields, **extra})
+        merged = {**fields, **extra}
+        if token is not None:
+            _OPEN_SPAN.reset(token)
+            _keep(SpanRecord(name, span_id, parent_id, call_id, t0, t1,
+                             threading.get_ident(), merged))
+        emit(name, duration_us=(t1 - t0) / 1e3, **merged)
 
 
 @contextlib.contextmanager

@@ -66,33 +66,34 @@ def plan_fft(
     """
     if mode not in ("estimate", "measure"):
         raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
-    cache = cache if cache is not None else default_cache()
-    key = problem_key(kind, shape, dtype, n_devices, direction, axes,
-                      precision, backends)
-    # Pencil problems can't be timed without a live mesh, and oaconv2d tile
-    # selection is a closed-form working-set/efficiency trade-off: the best
-    # we can do is the analytic model, so a cached ESTIMATE plan already is
-    # the answer for both kinds.
-    effective_mode = "estimate" if kind in _ESTIMATE_ONLY_KINDS else mode
-    degrade = _degrade_event(key, mode, effective_mode, "estimate_only_kind")
-    if not force:
-        hit = cache.get(key)
-        if hit is not None and (effective_mode == "estimate" or hit.mode == "measure"):
-            _resolve_event("plan_fft", key, mode, "hit", hit, cache)
-            return hit
-    if effective_mode == "measure":
-        plan = measure_plan(key, iters=measure_iters, timings_out=timings_out)
-        outcome = "measured"
-    else:
-        plan = estimate_plan(key)
-        outcome = "miss"
-        if degrade is not None:
-            plan = dataclasses.replace(plan, degrade_reason=degrade)
-    cache.put(plan)
-    if cache.path:
-        cache.save()
-    _resolve_event("plan_fft", key, mode, outcome, plan, cache)
-    return plan
+    with obs.span("plan.resolve", entry="plan_fft") as out:
+        cache = cache if cache is not None else default_cache()
+        key = problem_key(kind, shape, dtype, n_devices, direction, axes,
+                          precision, backends)
+        # Pencil problems can't be timed without a live mesh, and oaconv2d tile
+        # selection is a closed-form working-set/efficiency trade-off: the best
+        # we can do is the analytic model, so a cached ESTIMATE plan already is
+        # the answer for both kinds.
+        effective_mode = "estimate" if kind in _ESTIMATE_ONLY_KINDS else mode
+        degrade = _degrade_event(key, mode, effective_mode, "estimate_only_kind")
+        if not force:
+            hit = cache.get(key)
+            if hit is not None and (effective_mode == "estimate" or hit.mode == "measure"):
+                _resolve_event(out, key, mode, "hit", hit, cache)
+                return hit
+        if effective_mode == "measure":
+            plan = measure_plan(key, iters=measure_iters, timings_out=timings_out)
+            outcome = "measured"
+        else:
+            plan = estimate_plan(key)
+            outcome = "miss"
+            if degrade is not None:
+                plan = dataclasses.replace(plan, degrade_reason=degrade)
+        cache.put(plan)
+        if cache.path:
+            cache.save()
+        _resolve_event(out, key, mode, outcome, plan, cache)
+        return plan
 
 
 def _degrade_event(
@@ -119,23 +120,22 @@ def _degrade_event(
 
 
 def _resolve_event(
-    entry: str,
+    out: Dict,
     key: ProblemKey,
     mode: str,
     outcome: str,
     plan: FFTPlan,
     cache: Optional[PlanCache],
 ) -> None:
-    """One ``plan.resolve`` event per planner decision (+ outcome counter).
+    """Fill the decision's ``plan.resolve`` span (``out``, its extra
+    fields) and count the outcome.
 
     ``outcome`` is the cache verdict: ``"hit"`` (cached plan served),
     ``"miss"`` (fresh ESTIMATE), ``"measured"`` (a timed sweep ran),
     ``"forced"`` (a scoped variant pin replaced the planned engine).
     """
     obs.count(f"plan.resolve.{outcome}")
-    obs.emit(
-        "plan.resolve",
-        entry=entry,
+    out.update(
         kind=key.kind,
         shape=key.shape,
         dtype=key.dtype,
@@ -253,84 +253,88 @@ def resolve_call(
     the candidate sweep (outcome ``"quarantined"``), and the fallback
     plan is never written into the cache: wisdom must outlive the bench,
     the workaround must not.
+
+    The decision is timed as the ``plan.resolve`` span, whose event
+    carries the outcome, the key and the chosen plan.
     """
-    cfg = _active_config()
-    if cache is None:
-        cache = _cache_for_dir(cfg.cache_dir) if cfg.cache_dir else default_cache()
-    key = problem_key(kind, shape, dtype, n_devices, direction, axes,
-                      cfg.precision, cfg.backends)
-    mode = mode if mode is not None else cfg.mode
-    breaker = quarantine()
-    plan = cache.get(key)
-    hit = plan is not None
-    quarantined = hit and breaker.excluded(plan.variant, key)
-    if quarantined:
-        plan = None  # re-resolve around the benched engine
-    affected = quarantined or breaker.affects(key)
-    # A forced variant discards the planner's pick, so never pay a timed
-    # sweep inside the scope — the pin exists to skip planning costs.
-    # Either degrade (a variant pin, an analytic-only kind, a dirty trace)
-    # is recorded as a plan.degrade event AND — for fresh plans — on the
-    # plan's own degrade_reason, so wisdom files say why they are ESTIMATE.
-    degrade = None
-    if mode == "measure" and (plan is None or plan.mode != "measure"):
-        if cfg.variant is not None:
-            degrade = "forced_variant"
-        elif kind in _ESTIMATE_ONLY_KINDS:
-            degrade = "estimate_only_kind"
-        elif affected:
-            # Sweeping while an engine is benched would tune (and persist)
-            # wisdom over a temporarily reduced engine population.
-            degrade = "engine_quarantined"
-    want_measure = (
-        mode == "measure"
-        and degrade is None
-        and (plan is None or plan.mode != "measure")
-        # A measure_timeout plan means the sweep already hung once for
-        # this key; don't re-hang every call — plan_fft(force=True) is
-        # the explicit re-tune path.
-        and (plan is None or plan.degrade_reason != "measure_timeout")
-    )
-    measured = False
-    if want_measure and not _trace_safe():
-        degrade = "trace_not_clean"
-        want_measure = False
-    if degrade is not None:
-        _degrade_event(key, "measure", "estimate", degrade)
-    if want_measure:
-        plan = cache.put(measure_plan(key))
-        measured = True
-        if cache.path:
-            cache.save()
-    elif plan is None:
-        # ESTIMATE results stay in memory only: they are free to recompute,
-        # and a whole-file save here could clobber wisdom another process
-        # measured into the same file after we loaded it (it would also put
-        # file I/O inside jit traces). Only MEASURE results earn a write.
-        fresh = estimate_plan(key)
-        if degrade is not None:
-            fresh = dataclasses.replace(fresh, degrade_reason=degrade)
-        # Plans resolved under an active quarantine are workarounds, not
-        # wisdom: keep them out of the cache so the planned engine comes
-        # back the moment its breaker closes.
-        plan = fresh if affected else cache.put(fresh)
-    if cfg.variant is not None and cfg.variant != plan.variant:
-        # The key (and therefore plan.precision) already carries the scoped
-        # precision; only the engine choice itself can be forced.
-        plan = dataclasses.replace(
-            plan, variant=cfg.variant, mode="forced", measured_us=None,
-            degrade_reason=degrade,
+    with obs.span("plan.resolve", entry="resolve_call") as out:
+        cfg = _active_config()
+        if cache is None:
+            cache = _cache_for_dir(cfg.cache_dir) if cfg.cache_dir else default_cache()
+        key = problem_key(kind, shape, dtype, n_devices, direction, axes,
+                          cfg.precision, cfg.backends)
+        mode = mode if mode is not None else cfg.mode
+        breaker = quarantine()
+        plan = cache.get(key)
+        hit = plan is not None
+        quarantined = hit and breaker.excluded(plan.variant, key)
+        if quarantined:
+            plan = None  # re-resolve around the benched engine
+        affected = quarantined or breaker.affects(key)
+        # A forced variant discards the planner's pick, so never pay a timed
+        # sweep inside the scope — the pin exists to skip planning costs.
+        # Either degrade (a variant pin, an analytic-only kind, a dirty trace)
+        # is recorded as a plan.degrade event AND — for fresh plans — on the
+        # plan's own degrade_reason, so wisdom files say why they are ESTIMATE.
+        degrade = None
+        if mode == "measure" and (plan is None or plan.mode != "measure"):
+            if cfg.variant is not None:
+                degrade = "forced_variant"
+            elif kind in _ESTIMATE_ONLY_KINDS:
+                degrade = "estimate_only_kind"
+            elif affected:
+                # Sweeping while an engine is benched would tune (and persist)
+                # wisdom over a temporarily reduced engine population.
+                degrade = "engine_quarantined"
+        want_measure = (
+            mode == "measure"
+            and degrade is None
+            and (plan is None or plan.mode != "measure")
+            # A measure_timeout plan means the sweep already hung once for
+            # this key; don't re-hang every call — plan_fft(force=True) is
+            # the explicit re-tune path.
+            and (plan is None or plan.degrade_reason != "measure_timeout")
         )
-        _resolve_event("resolve_call", key, mode, "forced", plan, cache)
+        measured = False
+        if want_measure and not _trace_safe():
+            degrade = "trace_not_clean"
+            want_measure = False
+        if degrade is not None:
+            _degrade_event(key, "measure", "estimate", degrade)
+        if want_measure:
+            plan = cache.put(measure_plan(key))
+            measured = True
+            if cache.path:
+                cache.save()
+        elif plan is None:
+            # ESTIMATE results stay in memory only: they are free to recompute,
+            # and a whole-file save here could clobber wisdom another process
+            # measured into the same file after we loaded it (it would also put
+            # file I/O inside jit traces). Only MEASURE results earn a write.
+            fresh = estimate_plan(key)
+            if degrade is not None:
+                fresh = dataclasses.replace(fresh, degrade_reason=degrade)
+            # Plans resolved under an active quarantine are workarounds, not
+            # wisdom: keep them out of the cache so the planned engine comes
+            # back the moment its breaker closes.
+            plan = fresh if affected else cache.put(fresh)
+        if cfg.variant is not None and cfg.variant != plan.variant:
+            # The key (and therefore plan.precision) already carries the scoped
+            # precision; only the engine choice itself can be forced.
+            plan = dataclasses.replace(
+                plan, variant=cfg.variant, mode="forced", measured_us=None,
+                degrade_reason=degrade,
+            )
+            _resolve_event(out, key, mode, "forced", plan, cache)
+            return plan
+        outcome = (
+            "quarantined" if quarantined
+            else "measured" if measured
+            else "hit" if hit
+            else "miss"
+        )
+        _resolve_event(out, key, mode, outcome, plan, cache)
         return plan
-    outcome = (
-        "quarantined" if quarantined
-        else "measured" if measured
-        else "hit" if hit
-        else "miss"
-    )
-    _resolve_event("resolve_call", key, mode, outcome, plan, cache)
-    return plan
 
 
 def resolve(

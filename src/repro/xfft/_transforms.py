@@ -16,6 +16,11 @@ Every function here follows the same dispatch pipeline:
 5. apply the ``norm`` scaling on top of the engines' native convention
    (forward unscaled, inverse 1/N — i.e. ``"backward"``).
 
+Each transform call runs inside an ``xfft.call`` span (``repro.obs``)
+carrying the transform's name (``kind``), the input's shape and dtype:
+the root of the call's span records when profiling is on, whose children
+are ``plan.resolve``, ``engine.apply`` and the engine's stage spans.
+
 Precision handling: under ``xfft.config(precision="double")`` every
 public entry point runs its whole body inside ``jax.enable_x64`` — that
 is the only way jax lets 64-bit dtypes survive the plumbing (moveaxis,
@@ -34,6 +39,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from repro import obs
 from repro._x64 import enable_x64 as _enable_x64
 
 from repro.core.fft1d import _check_pow2 as _core_check_pow2
@@ -72,6 +78,23 @@ def _precision_scope(fn):
             with _enable_x64():
                 return fn(*args, **kwargs)
         return fn(*args, **kwargs)
+
+    return wrapper
+
+
+def _transform(fn):
+    """A front-door transform: an ``xfft.call`` span around the call, which
+    runs under :func:`_precision_scope`."""
+    scoped = _precision_scope(fn)
+    kind = fn.__name__
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        x = args[0] if args else kwargs.get("x")
+        dtype = getattr(x, "dtype", None)
+        with obs.span("xfft.call", kind=kind, shape=tuple(getattr(x, "shape", ())),
+                      dtype=None if dtype is None else str(dtype)):
+            return scoped(*args, **kwargs)
 
     return wrapper
 
@@ -159,7 +182,7 @@ def _moved_shape(shape: Tuple[int, ...], axis: int) -> Tuple[int, ...]:
 # ------------------------------ 1D complex ------------------------------
 
 
-@_precision_scope
+@_transform
 def fft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     """1D FFT along ``axis``; scipy.fft-compatible, plan-backed dispatch."""
     norm = _check_norm(norm)
@@ -174,7 +197,7 @@ def fft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     return _scale(y, norm, length, forward=True)
 
 
-@_precision_scope
+@_transform
 def ifft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     """Inverse 1D FFT along ``axis`` (norm-aware, plan-backed)."""
     norm = _check_norm(norm)
@@ -218,7 +241,7 @@ def _unmove_2d(y, canon, moved):
     return jnp.moveaxis(y, (-2, -1), canon) if moved else y
 
 
-@_precision_scope
+@_transform
 def fft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """2D FFT over ``axes``; scipy.fft-compatible, plan-backed dispatch."""
     x, norm, canon, moved = _prep_2d(x, s, axes, norm, "fft2")
@@ -228,7 +251,7 @@ def fft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     return _unmove_2d(_scale(y, norm, h * w, forward=True), canon, moved)
 
 
-@_precision_scope
+@_transform
 def ifft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """Inverse 2D FFT over ``axes`` (norm-aware, plan-backed)."""
     x, norm, canon, moved = _prep_2d(x, s, axes, norm, "ifft2")
@@ -253,7 +276,7 @@ def _fftn_axes(x, s, axes, name):
     return axes
 
 
-@_precision_scope
+@_transform
 def fftn(x, s=None, axes=None, norm: Optional[str] = None):
     """N-D FFT: separable 1D passes (a plan per axis); 2-axis calls take
     the dedicated ``fft2d`` planning kind via :func:`fft2`."""
@@ -272,7 +295,7 @@ def fftn(x, s=None, axes=None, norm: Optional[str] = None):
     return _scale(x, norm, total, forward=True)
 
 
-@_precision_scope
+@_transform
 def ifftn(x, s=None, axes=None, norm: Optional[str] = None):
     """Inverse N-D FFT (see :func:`fftn`)."""
     x = jnp.asarray(x)
@@ -295,7 +318,7 @@ def ifftn(x, s=None, axes=None, norm: Optional[str] = None):
 
 
 
-@_precision_scope
+@_transform
 def rfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     """Real-input FFT -> non-redundant half spectrum (..., N/2+1)."""
     norm = _check_norm(norm)
@@ -310,7 +333,7 @@ def rfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None)
     return _scale(y, norm, length, forward=True)
 
 
-@_precision_scope
+@_transform
 def irfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     """Inverse of :func:`rfft`: half spectrum -> real signal of length ``n``
     (default ``2*(width-1)``)."""
@@ -327,7 +350,7 @@ def irfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None
     return _scale(y, norm, length, forward=False)
 
 
-@_precision_scope
+@_transform
 def rfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """2D real-input FFT -> (..., H, W/2+1) half spectrum, plan-backed."""
     x = _real_input(x, "rfft2")
@@ -338,7 +361,7 @@ def rfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     return _unmove_2d(_scale(y, norm, h * w, forward=True), canon, moved)
 
 
-@_precision_scope
+@_transform
 def irfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """Inverse of :func:`rfft2`: (..., H, W/2+1) -> real (..., H, W)."""
     norm = _check_norm(norm)
@@ -368,7 +391,7 @@ def irfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
 # ------------------------------ N-D real ------------------------------
 
 
-@_precision_scope
+@_transform
 def rfftn(x, s=None, axes=None, norm: Optional[str] = None):
     """N-D real-input FFT: the two-for-one ``rfft`` along the LAST of
     ``axes``, complex passes over the rest — a real array never round-trips
@@ -395,7 +418,7 @@ def rfftn(x, s=None, axes=None, norm: Optional[str] = None):
     return _scale(y, norm, total, forward=True)
 
 
-@_precision_scope
+@_transform
 def irfftn(x, s=None, axes=None, norm: Optional[str] = None):
     """Inverse of :func:`rfftn`: complex inverse passes over the leading
     axes, then the half-spectrum ``irfft`` along the last -> real output."""

@@ -8,6 +8,8 @@ HBM temporaries where the transform is one round trip.
 """
 
 import math
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import fft_radix2 as k
+from repro.kernels.butterfly import butterfly_stage
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.slstm_scan import slstm_scan
 
 
 @pytest.fixture(scope="module")
@@ -119,3 +124,52 @@ def test_census_frame_fills_the_budget():
     assert k.pick_row_tile(32768, 2048) % 8 == 0
     # Longer rows fit one per block in the four-step layout, up to 2^18.
     assert k.fft_fits_vmem(1 << 18) and not k.fft_fits_vmem(1 << 19)
+
+
+# kernel name -> (entry point, input shapes): one entry per ``pallas_call``
+# under ``src/repro/kernels/``.
+NAMED = {
+    "repro_fft_fused": (lambda a, b: k.fft_fused(a, b, radix=4), [(256, 1024)] * 2),
+    "repro_fft_fused_split": (lambda a, b: k.fft_fused(a, b, radix=2), [(64, 4096)] * 2),
+    "repro_rfft_fused": (lambda a: k.rfft_fused(a, radix=4), [(256, 1024)]),
+    "repro_irfft_fused": (lambda a, b: k.irfft_fused(a, b, radix=4), [(256, 513)] * 2),
+    "repro_fft2_fused": (lambda a, b: k.fft2_fused(a, b, radix=4), [(2, 512, 512)] * 2),
+    "repro_rfft2_fused": (lambda a: k.rfft2_fused(a, radix=4), [(2, 512, 512)]),
+    "repro_irfft2_fused": (lambda a, b: k.irfft2_fused(a, b, radix=4), [(2, 512, 257)] * 2),
+    "repro_butterfly_stage": (lambda a, b: butterfly_stage(a, b, stage=2), [(8, 256)] * 2),
+    "repro_flash_attention_fwd": (flash_attention_fwd, [(2, 256, 128)] * 3),
+    "repro_slstm_scan": (lambda *args: slstm_scan(*args, chunk=8),
+                         [(2, 32, 256), (4, 16, 64), (256,)] + [(2, 64)] * 4),
+}
+
+
+def test_every_pallas_call_is_named():
+    """Each ``pallas_call`` in the kernels package passes a ``repro_`` name,
+    and each name is lowered below."""
+    src = pathlib.Path(k.__file__).parent
+    calls = names = 0
+    for path in src.glob("*.py"):
+        text = path.read_text()
+        calls += text.count("pl.pallas_call(")
+        found = re.findall(r'name="(repro_\w+)"', text)
+        names += len(found)
+        assert set(found) <= set(NAMED), path
+    assert calls == names == len(NAMED)
+
+
+@pytest.mark.parametrize("name", list(NAMED))
+def test_pallas_call_lowers_with_its_name(one_chip, name):
+    """The name reaches the Mosaic call, whose HLO instruction (and so the
+    operation in a device trace) is named after it."""
+    fn, shapes = NAMED[name]
+    lowered = jax.jit(fn).lower(*[_f32(s, one_chip) for s in shapes])
+    assert re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()) == [name]
+
+
+def test_compiled_kernel_keeps_its_name(one_chip):
+    """Compiled for the chip, the kernel's instruction carries the name the
+    trace reduction reports (``jit_rfft2_fused/repro_rfft2_fused``)."""
+    fn, shapes = NAMED["repro_rfft2_fused"]
+    text = jax.jit(fn).lower(*[_f32(s, one_chip) for s in shapes]).compile().as_text()
+    assert re.search(r"%repro_rfft2_fused(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+                     text)

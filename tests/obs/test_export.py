@@ -1,12 +1,10 @@
-"""Exporters: JSONL round-trip, Chrome trace structure, Prometheus text."""
+"""Exporters: JSONL round-trip, Prometheus text."""
 
 import json
 
 from repro.obs.export import (
-    chrome_trace,
     event_dict,
     prometheus_text,
-    write_chrome_trace,
     write_jsonl,
     write_prometheus,
 )
@@ -41,35 +39,6 @@ def test_event_dict_survives_exotic_field_values():
     assert d["fields"]["obj"] == "<weird>"
     assert d["fields"]["nested"]["a"] == [1, "<weird>"]
     assert d["fields"]["none"] is None
-
-
-def test_chrome_trace_spans_and_instants():
-    events = [
-        _ev("engine.apply", t=2.0, tid=5, duration_us=1000.0, engine="e"),
-        _ev("plan.resolve", t=1.0, tid=5, outcome="hit"),
-    ]
-    doc = chrome_trace(events, thread_names={5: "serve-loop[spectrum]"})
-    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    instants = [e for e in doc["traceEvents"] if e.get("ph") == "i"]
-    meta = [e for e in doc["traceEvents"] if e.get("ph") == "M"]
-    (span,) = spans
-    # emission happens at span EXIT: ts is start = t*1e6 - duration
-    assert span["ts"] == 2.0 * 1e6 - 1000.0
-    assert span["dur"] == 1000.0 and span["tid"] == 5
-    (inst,) = instants
-    assert inst["ts"] == 1.0 * 1e6
-    (m,) = meta
-    assert m["name"] == "thread_name"
-    assert m["args"]["name"] == "serve-loop[spectrum]"
-
-
-def test_chrome_trace_labels_unknown_threads(tmp_path):
-    doc = chrome_trace([_ev("a", tid=999)])
-    (m,) = [e for e in doc["traceEvents"] if e.get("ph") == "M"]
-    assert m["args"]["name"] == "thread-999"
-    path = write_chrome_trace([_ev("a", tid=999)], str(tmp_path / "t.json"))
-    loaded = json.load(open(path))
-    assert loaded["traceEvents"]
 
 
 def test_prometheus_counters_gauges_and_quantiles(tmp_path):

@@ -65,7 +65,7 @@ def test_background_thread_events_reach_recorder_not_caller_capture(recorder):
     ring = recorder.events()
     ticks = [e for e in ring if e.name == "serve.loop.tick"]
     assert ticks and all(e["service"] == "toy" for e in ticks)
-    # on a distinct thread lane (Chrome-trace tracks come from this map)
+    # on a distinct thread, named in the recorder's thread-name map
     loop_tids = {e.tid for e in ticks}
     caller_tid = threading.get_ident()
     assert caller_tid not in loop_tids
