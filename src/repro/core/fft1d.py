@@ -24,7 +24,9 @@ JAX mapping (see DESIGN.md §2):
     HBM round trip; ``fused_r4`` runs the radix-4 panel inside.
 
 All variants compute the same DFT and are tested against each other and a
-float64 DFT oracle.
+float64 DFT oracle. On the jnp variants (``looped`` to ``radix4``) each 1D
+pass, the cast, the axis moves and (inverse) the conjugations and scaling
+included, is one compiled program per shape (``repro_jnp_fft_pass``).
 
 Public transform calls belong to ``repro.xfft`` (plan-backed dispatch, no
 per-call variant kwargs); this module keeps the engines themselves
@@ -279,6 +281,36 @@ def _fft_radix4(x: jax.Array, n: int) -> jax.Array:
     return y.reshape(*batch, n)
 
 
+#: The jnp engines: complex64 FFT along the last axis, one body per variant.
+_JNP_BODIES = {
+    "looped": _fft_looped,
+    "unrolled": _fft_unrolled,
+    "stockham": _fft_stockham,
+    "radix4": _fft_radix4,
+}
+
+
+def _fft_jnp(x: jax.Array, variant: str) -> jax.Array:
+    """Forward jnp engine along the last axis (complex64 in and out)."""
+    return _JNP_BODIES[variant](x, x.shape[-1])
+
+
+def _ifft_jnp(x: jax.Array, variant: str) -> jax.Array:
+    """Inverse jnp engine along the last axis via the conjugation identity."""
+    return jnp.conj(_fft_jnp(jnp.conj(x), variant)) / x.shape[-1]
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "variant", "inverse"))
+def repro_jnp_fft_pass(
+    x: jax.Array, axis: int, variant: str, inverse: bool
+) -> jax.Array:
+    """One complex pass of a jnp engine along ``axis`` as one program: the
+    complex64 cast, the move to the last axis and back, and the stages."""
+    x = jnp.moveaxis(x.astype(jnp.complex64), axis, -1)
+    y = _ifft_jnp(x, variant) if inverse else _fft_jnp(x, variant)
+    return jnp.moveaxis(y, -1, axis)
+
+
 def fft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
     """Radix-2 FFT along ``axis``. Input real or complex; returns complex64.
 
@@ -304,26 +336,14 @@ def fft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Arr
         from repro.engines import apply_engine
 
         return apply_engine(variant, "fft1d", orig, axis=axis)
-    if x.dtype != jnp.complex64:
-        x = x.astype(jnp.complex64)
-    if axis != x.ndim - 1:
-        x = jnp.moveaxis(x, axis, -1)
-    n = x.shape[-1]
-    if variant == "looped":
-        y = _fft_looped(x, n)
-    elif variant == "unrolled":
-        y = _fft_unrolled(x, n)
-    elif variant == "stockham":
-        y = _fft_stockham(x, n)
-    elif variant == "radix4":
-        y = _fft_radix4(x, n)
-    else:  # fused / fused_r4
-        from repro.kernels.ops import fft_kernel  # lazy: kernels import core
+    if variant in _JNP_BODIES:
+        return repro_jnp_fft_pass(x, axis=axis, variant=variant, inverse=False)
+    # fused / fused_r4
+    from repro.kernels.ops import fft_kernel  # lazy: kernels import core
 
-        y = fft_kernel(x, radix=4 if variant == "fused_r4" else 2)
-    if axis != x.ndim - 1:
-        y = jnp.moveaxis(y, -1, axis)
-    return y
+    x = jnp.moveaxis(x.astype(jnp.complex64), axis, -1)
+    y = fft_kernel(x, radix=4 if variant == "fused_r4" else 2)
+    return jnp.moveaxis(y, -1, axis)
 
 
 def ifft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
@@ -344,6 +364,9 @@ def ifft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Ar
         from repro.engines import apply_engine  # lazy: registry fallback
 
         return apply_engine(variant, "fft1d", orig, direction="inv", axis=axis_n)
+    if variant in _JNP_BODIES:
+        _check_pow2(n, axis=axis)
+        return repro_jnp_fft_pass(x, axis=axis_n, variant=variant, inverse=True)
     x = x.astype(jnp.complex64)
     return jnp.conj(fft_impl(jnp.conj(x), axis=axis, variant=variant)) / n
 

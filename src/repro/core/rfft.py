@@ -20,12 +20,14 @@ planned through ``repro.plan`` under the ``rfft1d``/``rfft2d`` problem
 kinds). The public names are deprecated aliases of the ``repro.xfft``
 front door, which adds ``norm=`` conventions and plan-backed dispatch.
 
-The jnp 2D paths dispatch their row and column passes separately, each
-under a ``repro.obs`` span (``fft.rows``, ``fft.columns``).
+On the jnp engines each 1D pass (the row pass and the column pass of the
+2D paths) is one compiled program, dispatched under its ``repro.obs``
+span (``fft.rows``, ``fft.columns``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -37,6 +39,8 @@ from repro.core.fft1d import (
     BUILTIN_VARIANTS,
     Variant,
     _check_pow2,
+    _fft_jnp,
+    _ifft_jnp,
     fft_impl,
     ifft_impl,
 )
@@ -71,7 +75,7 @@ def _rfft_jnp(x: jax.Array, n: int, variant: Variant) -> jax.Array:
     """Pack N reals as N/2 complex, half-size FFT, symmetry recombination."""
     m = n // 2
     z = (x[..., 0::2] + 1j * x[..., 1::2]).astype(jnp.complex64)
-    zf = fft_impl(z, variant=variant) if m > 1 else z
+    zf = _fft_jnp(z, variant) if m > 1 else z
     k = jnp.arange(m + 1)
     zk = jnp.take(zf, k % m, axis=-1)               # Z[k], with Z[M] = Z[0]
     zmk = jnp.conj(jnp.take(zf, (-k) % m, axis=-1))  # conj(Z[(M-k) mod M])
@@ -94,9 +98,26 @@ def _irfft_jnp(y: jax.Array, n: int, variant: Variant) -> jax.Array:
     xe = 0.5 * (yk + ymk)
     xo = 0.5 * (yk - ymk) * jnp.exp(2j * jnp.pi * k / n).astype(jnp.complex64)
     z = xe + 1j * xo
-    zi = ifft_impl(z, variant=variant) if m > 1 else z
+    zi = _ifft_jnp(z, variant) if m > 1 else z
     out = jnp.stack([jnp.real(zi), jnp.imag(zi)], axis=-1)
     return out.reshape(*zi.shape[:-1], n).astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "variant"))
+def repro_jnp_rfft_pass(x: jax.Array, axis: int, variant: str) -> jax.Array:
+    """The real forward pass of a jnp engine along ``axis`` as one program:
+    the float32 cast, the moves each way, the pack, the half-size FFT and
+    the recombination."""
+    x = jnp.moveaxis(x.astype(jnp.float32), axis, -1)
+    return jnp.moveaxis(_rfft_jnp(x, x.shape[-1], variant), -1, axis)
+
+
+@functools.partial(jax.jit, static_argnames=("axis", "variant"))
+def repro_jnp_irfft_pass(y: jax.Array, axis: int, variant: str) -> jax.Array:
+    """The real inverse pass of a jnp engine along ``axis`` as one program."""
+    y = jnp.moveaxis(y.astype(jnp.complex64), axis, -1)
+    out = _irfft_jnp(y, 2 * (y.shape[-1] - 1), variant)
+    return jnp.moveaxis(out, -1, axis)
 
 
 def rfft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
@@ -116,18 +137,12 @@ def rfft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Ar
         from repro.engines import apply_engine
 
         return apply_engine(variant, "rfft1d", orig, axis=axis)
-    x = x.astype(jnp.float32)
-    if axis != x.ndim - 1:
-        x = jnp.moveaxis(x, axis, -1)
-    if variant in _FUSED:
-        from repro.kernels.ops import rfft_kernel  # lazy: kernels import core
+    if variant not in _FUSED:
+        return repro_jnp_rfft_pass(x, axis=axis, variant=variant)
+    from repro.kernels.ops import rfft_kernel  # lazy: kernels import core
 
-        y = rfft_kernel(x, radix=_radix(variant))
-    else:
-        y = _rfft_jnp(x, n, variant)
-    if axis != x.ndim - 1:
-        y = jnp.moveaxis(y, -1, axis)
-    return y
+    x = jnp.moveaxis(x.astype(jnp.float32), axis, -1)
+    return jnp.moveaxis(rfft_kernel(x, radix=_radix(variant)), -1, axis)
 
 
 def irfft_impl(y: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
@@ -148,18 +163,12 @@ def irfft_impl(y: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.A
         from repro.engines import apply_engine  # lazy: registry fallback
 
         return apply_engine(variant, "rfft1d", orig, direction="inv", axis=axis)
-    y = y.astype(jnp.complex64)
-    if axis != y.ndim - 1:
-        y = jnp.moveaxis(y, axis, -1)
-    if variant in _FUSED:
-        from repro.kernels.ops import irfft_kernel  # lazy: kernels import core
+    if variant not in _FUSED:
+        return repro_jnp_irfft_pass(y, axis=axis, variant=variant)
+    from repro.kernels.ops import irfft_kernel  # lazy: kernels import core
 
-        out = irfft_kernel(y, radix=_radix(variant))
-    else:
-        out = _irfft_jnp(y, n, variant)
-    if axis != y.ndim - 1:
-        out = jnp.moveaxis(out, -1, axis)
-    return out
+    y = jnp.moveaxis(y.astype(jnp.complex64), axis, -1)
+    return jnp.moveaxis(irfft_kernel(y, radix=_radix(variant)), -1, axis)
 
 
 def rfft2_impl(x: jax.Array, variant: Variant = "auto") -> jax.Array:
