@@ -27,11 +27,7 @@ SIZE = 1024
 
 
 def run():
-    from repro.core.distributed import (
-        fft2_pencil,
-        fft2_pencil_overlapped,
-        pencil_sharding,
-    )
+    from repro.core.distributed import pencil_sharding, repro_pencil_fft2
     from repro.launch.mesh import make_mesh
 
     n_dev = len(jax.devices())
@@ -49,10 +45,11 @@ def run():
     x = rng.standard_normal((SIZE, SIZE)).astype(np.float32)
     xs = jax.device_put(jnp.asarray(x), pencil_sharding(mesh, "data", "rows"))
 
-    plain = jax.jit(lambda v: fft2_pencil(v, mesh, variant="stockham"))
-    over = jax.jit(
-        lambda v: fft2_pencil_overlapped(v, mesh, variant="stockham", chunks=4)
-    )
+    def pencil(chunks):
+        return jax.jit(lambda v: repro_pencil_fft2(
+            v, mesh=mesh, axis="data", layout="rows", variant="stockham", chunks=chunks))
+
+    plain, over = pencil(1), pencil(4)
     for name, fn in (("plain", plain), ("overlapped", over)):
         jax.block_until_ready(fn(xs))
         ts = []

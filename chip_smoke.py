@@ -1,7 +1,7 @@
 """Chip smoke run: drive the planned FFT service once on a TPU and check it.
 
     python chip_smoke.py [--seed N]      # one chip: lanes (a)-(c), MEASURE, double refusal
-    python chip_smoke.py --four-chips    # four chips: overlapped pencil FFT only
+    python chip_smoke.py --four-chips    # four chips: xfft.fft2 on a row-sharded grid only
 
 One process, no children. The default run serves, through one
 ``ImagingService`` at its default planning mode:
@@ -222,7 +222,8 @@ def run_four_chips(seed: int, info: dict) -> None:
     import jax
 
     import repro.xfft as xfft
-    from repro.core.distributed import fft2_pencil_overlapped, pencil_sharding
+    from repro import obs
+    from repro.core.distributed import pencil_sharding, repro_pencil_fft2
     from repro.launch.mesh import make_mesh
 
     check(info["count"] == 4, f"--four-chips needs 4 devices, found {info['count']}")
@@ -232,15 +233,21 @@ def run_four_chips(seed: int, info: dict) -> None:
          + 1j * rng.standard_normal((n, n), np.float32)).astype(np.complex64)
     mesh = make_mesh((4,), ("data",))
     xs = jax.device_put(x, pencil_sharding(mesh, "data", "rows"))
-    # Four slabs, so the overlapped corner turn really is chunked (the
-    # planner's ESTIMATE picks one slab at this size).
-    fn = jax.jit(lambda v: fft2_pencil_overlapped(v, mesh, chunks=4))
-    t0 = time.perf_counter()
-    y = jax.block_until_ready(fn(xs))
-    first_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    y = jax.block_until_ready(fn(xs))
-    warm_s = time.perf_counter() - t0
+    # The front door plans the row-sharded grid as a pencil transform.
+    with obs.capture() as trace:
+        t0 = time.perf_counter()
+        y = jax.block_until_ready(xfft.fft2(xs))
+        first_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        y = jax.block_until_ready(xfft.fft2(xs))
+        warm_s = time.perf_counter() - t0
+    resolved = trace.select("plan.resolve")
+    check(resolved and all(e["kind"] == "fft2d_pencil" and e["n_devices"] == 4
+                           for e in resolved), f"not planned as a pencil: {resolved}")
+    dispatch = trace.select("pencil.dispatch")
+    check(len(dispatch) == 2, f"{len(dispatch)} pencil dispatches for 2 calls")
+    bad = forbidden_events(trace)
+    check(not bad, f"fallback or failure events: {[(e.name, e.fields) for e in bad]}")
 
     def quarters(arr, shard_shape):
         shards = arr.addressable_shards
@@ -249,7 +256,10 @@ def run_four_chips(seed: int, info: dict) -> None:
 
     check(quarters(xs, (n // 4, n)), "input is not split into row quarters")
     check(quarters(y, (n, n // 4)), "output is not split into column quarters")
-    hlo = fn.lower(xs).compile().as_text()
+    plan = dispatch[-1]
+    hlo = repro_pencil_fft2.lower(xs, mesh=mesh, axis="data", layout="rows",
+                                  variant=plan["variant"],
+                                  chunks=plan["chunks"]).compile().as_text()
     check("all-to-all" in hlo, "compiled pencil FFT has no all-to-all")
 
     got = np.asarray(y)
@@ -258,7 +268,8 @@ def run_four_chips(seed: int, info: dict) -> None:
     err_np = scaled_err(got, ref)
     err_one = scaled_err(got, one)
     print(f"four-chip pencil fft2 {n}x{n} complex64: " + json.dumps({
-        "first_s": first_s, "warm_s": warm_s, "err_vs_numpy": err_np,
+        "first_s": first_s, "warm_s": warm_s, "variant": plan["variant"],
+        "chunks": plan["chunks"], "err_vs_numpy": err_np,
         "err_vs_one_chip": err_one, "one_chip_err_vs_numpy": scaled_err(one, ref),
     }), flush=True)
     check(err_np <= SINGLE_ATOL, f"pencil FFT off numpy: {err_np:.3e}")
@@ -269,7 +280,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the four-chip overlapped pencil FFT phase")
+                    help="run only the four-chip sharded xfft.fft2 phase")
     args = ap.parse_args(argv)
 
     info = device_info()

@@ -9,7 +9,9 @@ reads off these specs.
 
 Capability parity with the pre-registry planner is deliberate and tested:
 
-* the four jnp engines serve every problem kind at any device count;
+* the four jnp engines serve every problem kind; the sharded
+  ``fft2d_pencil`` kind only across two or more devices, and only while
+  the pencil program's per-chip working set fits the chip's HBM;
 * the fused kernels serve the 1D/2D complex+real kinds only, single
   device, power-of-two dims, and only while a 1D row tile fits the VMEM
   budget (``working_set``) — the exact gate ``variant_candidates`` used
@@ -19,6 +21,7 @@ Capability parity with the pre-registry planner is deliberate and tested:
 from __future__ import annotations
 
 import functools
+import math
 
 from repro.engines.registry import CostHints, EngineSpec, register_engine
 
@@ -60,11 +63,42 @@ def _core_ops(name: str):
             from repro.core.fft2d import fft2_stream
 
             return functools.partial(fft2_stream, variant=name)
-        # fft2d_pencil needs a mesh and oaconv2d a (image, kernel) pair;
-        # both execute at the plan level (repro.plan.execute), not here.
+        if kind == "fft2d_pencil":
+            from repro.core.distributed import pencil_fft2
+
+            # Takes the sharded grid and the plan's ``chunks``; the mesh,
+            # its axis and the layout are read from the grid's sharding.
+            return functools.partial(pencil_fft2, variant=name, inverse=inv)
+        # oaconv2d needs an (image, kernel) pair; it executes at the plan
+        # level (repro.plan.execute), not here.
         return None
 
     return factory
+
+
+#: Per-chip working set of the pencil program, in blocks of the grid's
+#: per-chip share (8·H·W/d bytes): the input, the output and at most 2.56
+#: blocks of temporaries (each of the four jnp variants compiled for a v5e
+#: 2x2 at 32768², chunks 1-16; 1.5-2.0 at chunks 1).
+_PENCIL_BLOCKS = 4.6
+
+
+def _jnp_predicate(key) -> bool:
+    """The jnp engines serve a pencil key only across two or more devices,
+    and on a TPU only where the pencil program's per-chip working set fits
+    the chip's HBM (no chunking shrinks it: the first pass holds whole
+    blocks)."""
+    if key.kind != "fft2d_pencil":
+        return True
+    if key.n_devices < 2:
+        return False
+    if key.backend != "tpu":
+        return True
+    from repro.launch.roofline import chip_peaks  # lazy: launch imports jax
+
+    elem = 16 if key.precision == "double" else 8
+    block = elem * math.prod(key.shape) / key.n_devices
+    return _PENCIL_BLOCKS * block <= chip_peaks(key.device_kind).hbm_bytes
 
 
 def _fused_predicate(key) -> bool:
@@ -117,6 +151,7 @@ def _register_builtin_engines() -> None:
             radix=radix,
             cost=cost,
             ops=_core_ops(name),
+            predicate=_jnp_predicate,
             # stockham is the canonical always-works rung: pure jnp ops,
             # every kind, no VMEM cliff — the degradation ladder's bottom.
             reliable=(name == "stockham"),

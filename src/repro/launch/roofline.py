@@ -21,6 +21,7 @@ class ChipPeaks:
     flops_bf16: float    # FLOP/s
     hbm_bw: float        # B/s
     ici_link_bw: float   # B/s per interconnect link
+    hbm_bytes: float     # B of HBM per chip
     source: str
 
 
@@ -31,6 +32,7 @@ PEAKS = {
         flops_bf16=197e12,
         hbm_bw=819e9,
         ici_link_bw=50e9,
+        hbm_bytes=16e9,
         source=(
             "Google Cloud TPU docs, 'TPU v5e': 197 TFLOP/s bf16, 16 GB HBM "
             "at 819 GB/s, 1,600 Gbit/s ICI over 4 links"

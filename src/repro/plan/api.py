@@ -47,6 +47,7 @@ def plan_fft(
     axes: Optional[Tuple[int, ...]] = None,
     precision: str = "single",
     backends: Tuple[str, ...] = (),
+    layout: str = "",
 ) -> FFTPlan:
     """Plan one FFT problem; consult the cache first unless ``force``.
 
@@ -63,13 +64,14 @@ def plan_fft(
     as a scale outside the engine, so all conventions share one entry.
     ``precision`` and ``backends`` restrict which registered engines the
     planner may consider (``repro.engines``) and are part of the key.
+    ``layout`` is a pencil problem's input layout (``"rows"``/``"cols"``).
     """
     if mode not in ("estimate", "measure"):
         raise ValueError(f"mode must be 'estimate' or 'measure', got {mode!r}")
     with obs.span("plan.resolve", entry="plan_fft") as out:
         cache = cache if cache is not None else default_cache()
         key = problem_key(kind, shape, dtype, n_devices, direction, axes,
-                          precision, backends)
+                          precision, backends, layout)
         # Pencil problems can't be timed without a live mesh, and oaconv2d tile
         # selection is a closed-form working-set/efficiency trade-off: the best
         # we can do is the analytic model, so a cached ESTIMATE plan already is
@@ -140,6 +142,8 @@ def _resolve_event(
         shape=key.shape,
         dtype=key.dtype,
         direction=key.direction,
+        n_devices=key.n_devices,
+        layout=key.layout,
         precision=key.precision,
         backend=key.backend,
         mode=mode,
@@ -224,6 +228,7 @@ def resolve_call(
     direction: str = "fwd",
     axes: Optional[Tuple[int, ...]] = None,
     mode: Optional[str] = None,
+    layout: str = "",
 ) -> FFTPlan:
     """Resolve one transform *call* to a concrete plan, config applied.
 
@@ -245,7 +250,10 @@ def resolve_call(
        than jitting mid-trace.
     3. A scoped ``variant=...`` override replaces the planned schedule
        (the returned plan is marked ``mode="forced"`` and never cached:
-       forced choices are opinions, not wisdom).
+       forced choices are opinions, not wisdom). On a problem sharded over
+       several devices, a pin (or a ``backend`` scope) that leaves no
+       engine serving it raises ``ValueError`` naming the engines that do:
+       no single-device engine runs a sharded grid.
 
     Resilience: a cached plan whose engine is quarantined for this key
     (``repro.resilience`` circuit breaker open after a failure) is NOT
@@ -262,7 +270,9 @@ def resolve_call(
         if cache is None:
             cache = _cache_for_dir(cfg.cache_dir) if cfg.cache_dir else default_cache()
         key = problem_key(kind, shape, dtype, n_devices, direction, axes,
-                          cfg.precision, cfg.backends)
+                          cfg.precision, cfg.backends, layout)
+        if key.n_devices > 1 and (cfg.variant is not None or key.backends):
+            _check_sharded_scope(key, cfg.variant)
         mode = mode if mode is not None else cfg.mode
         breaker = quarantine()
         plan = cache.get(key)
@@ -337,6 +347,29 @@ def resolve_call(
         return plan
 
 
+def _check_sharded_scope(key: ProblemKey, variant: Optional[str]) -> None:
+    """Refuse, by name, a scoped pin or backend restriction that leaves no
+    engine for a problem sharded over several devices: a single-device
+    engine would need the whole grid on one device."""
+    from repro.engines import get_engine, iter_engines  # lazy: leaf layer
+
+    if variant is not None:
+        if get_engine(variant).supports(key):
+            return
+        scope = f"xfft.config(variant={variant!r})"
+    else:
+        if any(s.supports(key) for s in iter_engines()):
+            return
+        scope = f"xfft.config(backend={key.backends!r})"
+    unscoped = dataclasses.replace(key, backends=())
+    serving = tuple(s.name for s in iter_engines() if s.supports(unscoped))
+    raise ValueError(
+        f"{scope} leaves no engine for a {key.kind} problem of shape {key.shape} "
+        f"sharded over {key.n_devices} devices; the engines that serve it are "
+        f"{serving}: pin one of those, or put the array on one device first"
+    )
+
+
 def resolve(
     kind: str,
     shape: Tuple[int, ...],
@@ -357,13 +390,15 @@ def execute(plan: FFTPlan, x, mesh=None, axis: str = "data"):
     """Run ``x`` through the transform ``plan`` was made for.
 
     Pencil plans need the ``mesh`` (and device-axis name) the plan's
-    ``n_devices`` refers to.
+    ``n_devices`` refers to: ``x`` is placed on it in the plan key's
+    layout, and the plan's engine runs it as ``repro.xfft`` would run a
+    grid sharded so.
 
-    Single-device kinds run through the resilience degradation ladder
+    Every kind but oaconv2d runs through the resilience degradation ladder
     (:func:`repro.resilience.run_plan`): an engine failure is quarantined
     and the call retries the next-best healthy rung instead of raising.
-    The pencil and oaconv2d composites dispatch directly — their variants
-    compose per-pass engines that each ladder on their own.
+    The oaconv2d composite dispatches directly — its transforms ladder on
+    their own.
     """
     kind = plan.key.kind
     inv = plan.key.direction == "inv"
@@ -396,11 +431,14 @@ def execute(plan: FFTPlan, x, mesh=None, axis: str = "data"):
     if kind == "fft2d_pencil":
         if mesh is None:
             raise ValueError("execute() needs mesh=... for a pencil plan")
-        from repro.core.distributed import fft2_pencil_overlapped
+        import jax
 
-        return fft2_pencil_overlapped(
-            x, mesh, axis=axis, variant=plan.variant, chunks=plan.chunks
-        )
+        from repro.core.distributed import pencil_sharding
+        from repro.engines import get_engine
+
+        x = jax.device_put(x, pencil_sharding(mesh, axis, plan.key.layout, x.ndim))
+        return run_plan(plan, lambda v: get_engine(v).op(kind, plan.key.direction)(
+            x, chunks=plan.chunks))
     if kind == "oaconv2d":
         from repro.imaging.tiled import oaconvolve2
 

@@ -68,6 +68,10 @@ NORMS = ("backward", "ortho", "forward")
 #: ``precision == "double"``.
 _WIDE_DTYPES = {"complex64": "complex128", "float32": "float64"}
 
+#: Input layouts of a sharded (``fft2d_pencil``) problem: which of the two
+#: transform axes the devices split. Single-device kinds carry none.
+LAYOUTS = ("rows", "cols")
+
 #: Canonical transform axes per kind — the axes every entry point moves the
 #: transform onto before keying (1D kinds transform the last axis, 2D kinds
 #: the trailing two). A ProblemKey built without explicit axes gets these,
@@ -89,7 +93,10 @@ class ProblemKey:
 
     ``shape`` is the concrete array shape seen by the entry point (for
     ``fft1d`` the transform axis is last; for 2D kinds the trailing two
-    axes are H, W; for ``fft2d_stream`` the leading axis is time).
+    axes are H, W; for ``fft2d_stream`` the leading axis is time; for
+    ``fft2d_pencil`` the global shape). ``layout`` is the sharded input's
+    layout (:data:`LAYOUTS`, ``"rows"`` by default) on ``fft2d_pencil``
+    keys, and empty on every other kind.
     """
 
     kind: str                  # one of KINDS
@@ -102,6 +109,7 @@ class ProblemKey:
     axes: Tuple[int, ...] = () # transform axes; () -> canonical for the kind
     precision: str = "single"  # "single" | "double" — engine-capability filter
     backends: Tuple[str, ...] = ()  # engine-backend scope; () = unrestricted
+    layout: str = ""           # pencil input layout: "rows" | "cols"
 
     def __post_init__(self):
         if self.kind not in KINDS:
@@ -131,6 +139,14 @@ class ProblemKey:
         # Canonicalize the engine-backend scope (sorted, deduplicated) so
         # config(backend=("pallas", "jnp")) and ("jnp", "pallas") share keys.
         object.__setattr__(self, "backends", tuple(sorted(set(self.backends))))
+        if self.kind == "fft2d_pencil":
+            object.__setattr__(self, "layout", self.layout or "rows")
+            if self.layout not in LAYOUTS:
+                raise ValueError(
+                    f"unknown pencil layout {self.layout!r}; want one of {LAYOUTS}"
+                )
+        elif self.layout:
+            raise ValueError(f"kind {self.kind!r} takes no layout, got {self.layout!r}")
 
     def cache_key(self) -> str:
         """Stable, versioned string key for the plan cache.
@@ -143,14 +159,15 @@ class ProblemKey:
         shape = "x".join(str(s) for s in self.shape)
         axes = ",".join(str(a) for a in self.axes)
         engines = ",".join(self.backends) if self.backends else "*"
+        layout = f"|{self.layout}" if self.layout else ""
         return (
             f"v{PLAN_SCHEMA_VERSION}|{self.kind}|{self.direction}|{self.backend}"
             f"|{self.device_kind}|{shape}|{self.dtype}|d{self.n_devices}"
-            f"|ax{axes}|{self.precision}|be{engines}"
+            f"|ax{axes}|{self.precision}|be{engines}{layout}"
         )
 
     def to_dict(self) -> dict:
-        return {
+        out = {
             "kind": self.kind,
             "backend": self.backend,
             "device_kind": self.device_kind,
@@ -162,6 +179,9 @@ class ProblemKey:
             "precision": self.precision,
             "backends": list(self.backends),
         }
+        if self.layout:
+            out["layout"] = self.layout
+        return out
 
     @classmethod
     def from_dict(cls, d: dict) -> "ProblemKey":
@@ -176,6 +196,7 @@ class ProblemKey:
             axes=tuple(d.get("axes", ())),
             precision=d.get("precision", "single"),
             backends=tuple(d.get("backends", ())),
+            layout=d.get("layout", ""),
         )
 
 
@@ -276,6 +297,7 @@ def problem_key(
     axes: Optional[Tuple[int, ...]] = None,
     precision: str = "single",
     backends: Tuple[str, ...] = (),
+    layout: str = "",
 ) -> ProblemKey:
     """Build a :class:`ProblemKey` for the *current* JAX backend/device.
 
@@ -286,6 +308,7 @@ def problem_key(
     ``precision`` and ``backends`` are the engine-capability constraints
     resolution runs under (schema v5); both come from the scoped
     ``repro.xfft.config`` when resolution goes through ``resolve_call``.
+    ``layout`` is a pencil key's input layout (``"rows"`` when empty).
     """
     import jax
 
@@ -301,6 +324,7 @@ def problem_key(
         axes=tuple(axes) if axes else (),
         precision=precision,
         backends=tuple(backends),
+        layout=layout,
     )
 
 

@@ -15,6 +15,11 @@ failure, the call retries one rung down. If every rung yields non-finite
 values the last output is returned as-is — at that point the *input* is
 poisoned and no engine can do better.
 
+Rungs come from the failed plan's own problem key, so a sharded
+(``fft2d_pencil``) call fails over only to another engine that serves
+that key across its devices, never to a single-device engine that would
+gather the grid.
+
 Forced plans (``xfft.config(variant=...)``) bypass the ladder entirely:
 a pin is an explicit opinion, and tests that pin an engine must observe
 exactly that engine, faults and all.
@@ -102,6 +107,7 @@ def run_plan(plan, runner: Callable[[str], Any]):
             "engine.apply", engine=plan.variant, backend=backend,
             kind=plan.key.kind, direction=plan.key.direction,
             shape=plan.key.shape, precision=plan.key.precision, x64=x64,
+            n_devices=plan.key.n_devices,
         ) as sp:
             out = runner(plan.variant)
             sp["ok"] = True
@@ -127,7 +133,7 @@ def run_plan(plan, runner: Callable[[str], Any]):
             with obs.span(
                 "engine.apply", engine=variant, backend=backend,
                 kind=key.kind, direction=key.direction, shape=key.shape,
-                precision=key.precision, x64=x64,
+                precision=key.precision, x64=x64, n_devices=key.n_devices,
             ) as sp:
                 out = faults.maybe_corrupt(
                     "engine.apply", runner(variant), engine=variant,

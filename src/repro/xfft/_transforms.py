@@ -16,6 +16,15 @@ Every function here follows the same dispatch pipeline:
 5. apply the ``norm`` scaling on top of the engines' native convention
    (forward unscaled, inverse 1/N — i.e. ``"backward"``).
 
+Sharded input: ``fft2``/``ifft2`` (and ``fftn``/``ifftn`` over two axes)
+given a grid sharded over two or more devices along one of its two
+transform axes (one mesh axis, every other axis replicated) resolve an
+``fft2d_pencil`` plan keyed by the device count and the layout, and run
+a pencil engine through the same ladder: a rows-sharded input comes back
+sharded in columns and a columns-sharded one in rows, so
+``ifft2(fft2(x))`` keeps ``x``'s layout. Nothing is gathered. Every other
+input takes the single-device path.
+
 Each transform call runs inside an ``xfft.call`` span (``repro.obs``)
 carrying the transform's name (``kind``), the input's shape and dtype:
 the root of the call's span records when profiling is on, whose children
@@ -50,11 +59,13 @@ from repro.core.fft2d import fft2_impl as _fft2_impl
 from repro.core.fft2d import fftshift2 as _core_fftshift2
 from repro.core.fft2d import ifft2_impl as _ifft2_impl
 from repro.core.fft2d import ifftshift2 as _core_ifftshift2
+from repro.core.distributed import pencil_layout as _pencil_layout
 from repro.core.rfft import _ensure_real  # one real-input contract
 from repro.core.rfft import irfft2_impl as _irfft2_impl
 from repro.core.rfft import irfft_impl as _irfft_impl
 from repro.core.rfft import rfft2_impl as _rfft2_impl
 from repro.core.rfft import rfft_impl as _rfft_impl
+from repro.engines import get_engine as _get_engine
 from repro.plan.api import resolve_call
 from repro.plan.plan import NORMS
 from repro.resilience.ladder import run_plan as _run_plan
@@ -241,13 +252,29 @@ def _unmove_2d(y, canon, moved):
     return jnp.moveaxis(y, (-2, -1), canon) if moved else y
 
 
+def _complex_2d(x, direction: str):
+    """Plan and run one complex 2D transform of the trailing two axes: a
+    grid sharded in rows or columns over several devices on its pencil plan,
+    any other input on the single-device plan (engines' backward norm)."""
+    sharded = _pencil_layout(x)
+    if sharded is None:
+        impl = _ifft2_impl if direction == "inv" else _fft2_impl
+        plan = resolve_call("fft2d", x.shape, direction=direction)
+        return _run_plan(plan, lambda v: impl(x, variant=v))
+    mesh, axis, layout = sharded
+    obs.count("xfft.sharded_calls")
+    plan = resolve_call("fft2d_pencil", x.shape, n_devices=mesh.shape[axis],
+                        direction=direction, layout=layout)
+    return _run_plan(plan, lambda v: _get_engine(v).op("fft2d_pencil", direction)(
+        x, chunks=plan.chunks))
+
+
 @_transform
 def fft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """2D FFT over ``axes``; scipy.fft-compatible, plan-backed dispatch."""
     x, norm, canon, moved = _prep_2d(x, s, axes, norm, "fft2")
     h, w = x.shape[-2], x.shape[-1]
-    plan = resolve_call("fft2d", x.shape)
-    y = _run_plan(plan, lambda v: _fft2_impl(x, variant=v))
+    y = _complex_2d(x, "fwd")
     return _unmove_2d(_scale(y, norm, h * w, forward=True), canon, moved)
 
 
@@ -256,8 +283,7 @@ def ifft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     """Inverse 2D FFT over ``axes`` (norm-aware, plan-backed)."""
     x, norm, canon, moved = _prep_2d(x, s, axes, norm, "ifft2")
     h, w = x.shape[-2], x.shape[-1]
-    plan = resolve_call("fft2d", x.shape, direction="inv")
-    y = _run_plan(plan, lambda v: _ifft2_impl(x, variant=v))
+    y = _complex_2d(x, "inv")
     return _unmove_2d(_scale(y, norm, h * w, forward=False), canon, moved)
 
 

@@ -11,39 +11,43 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
+from repro import xfft
 from repro.launch.mesh import make_mesh
-from repro.core.distributed import fft2_pencil, fft2_pencil_overlapped, pencil_sharding
+from repro.core.distributed import pencil_sharding, repro_pencil_fft2
 
 mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(7)
 
-# sharded input, plain + overlapped variants, batched too
+# sharded input, the pencil program with 1, 4 and 2 corner-turn slabs
 x = rng.standard_normal((64, 32)).astype(np.float32)
 xs = jax.device_put(jnp.asarray(x), pencil_sharding(mesh, "data", "rows"))
 ref = np.fft.fft2(x)
 scale = np.max(np.abs(ref))
-for fn, kw in ((fft2_pencil, {}), (fft2_pencil_overlapped, {"chunks": 4}),
-               (fft2_pencil_overlapped, {"chunks": 2})):
-    got = np.asarray(fn(xs, mesh, **kw))
-    err = np.max(np.abs(got - ref)) / scale
-    assert err < 1e-5, (fn.__name__, kw, err)
+for chunks in (1, 4, 2):
+    y = repro_pencil_fft2(xs, mesh=mesh, axis="data", layout="rows", variant="looped",
+                          chunks=chunks)
+    err = np.max(np.abs(np.asarray(y) - ref)) / scale
+    assert err < 1e-5, (chunks, err)
+    # the output really lands column-sharded, whatever the slab count
+    assert tuple(y.sharding.spec) == (None, "data"), (chunks, y.sharding.spec)
 
-# planner integration: variant/chunks resolved through repro.plan
+# planner integration: the front door resolves variant and chunks through
+# repro.plan for the sharded grid
 from repro.plan import default_cache, problem_key
-got = np.asarray(fft2_pencil_overlapped(xs, mesh, variant="auto", chunks="auto"))
-assert np.max(np.abs(got - ref)) / scale < 1e-5, "auto pencil mismatch"
-plan = default_cache().get(problem_key("fft2d_pencil", (64, 32), n_devices=8))
+y = xfft.fft2(xs)
+assert np.max(np.abs(np.asarray(y) - ref)) / scale < 1e-5, "planned pencil mismatch"
+assert tuple(y.sharding.spec) == (None, "data"), y.sharding.spec
+plan = default_cache().get(problem_key("fft2d_pencil", (64, 32), n_devices=8,
+                                       layout="rows"))
 assert plan is not None and plan.variant in ("looped", "unrolled", "stockham", "radix4")
 assert 32 % plan.chunks == 0 and (32 // plan.chunks) % 8 == 0, plan.chunks
 
+# batched: leading axes replicated, rows sharded
 xb = rng.standard_normal((3, 64, 64)).astype(np.float32)
-gb = np.asarray(fft2_pencil(jnp.asarray(xb), mesh))
-assert np.max(np.abs(gb - np.fft.fft2(xb))) / np.max(np.abs(np.fft.fft2(xb))) < 1e-5
-
-# output really lands column-sharded for the plain variant
-y = fft2_pencil(xs, mesh)
-spec = y.sharding.spec
-assert tuple(spec) == (None, "data"), spec
+xbs = jax.device_put(jnp.asarray(xb), pencil_sharding(mesh, "data", "rows", ndim=3))
+gb = xfft.fft2(xbs)
+assert np.max(np.abs(np.asarray(gb) - np.fft.fft2(xb))) / np.max(np.abs(np.fft.fft2(xb))) < 1e-5
+assert tuple(gb.sharding.spec) == (None, None, "data"), gb.sharding.spec
 print("DISTRIBUTED_OK")
 """
 

@@ -173,3 +173,34 @@ def test_compiled_kernel_keeps_its_name(one_chip):
     text = jax.jit(fn).lower(*[_f32(s, one_chip) for s in shapes]).compile().as_text()
     assert re.search(r"%repro_rfft2_fused(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
                      text)
+
+
+@pytest.mark.parametrize("variant", ["looped", "unrolled", "stockham", "radix4"])
+@pytest.mark.parametrize("layout,inverse,chunks",
+                         [("rows", True, 1), ("cols", False, 4), ("rows", False, 16)])
+def test_pencil_program_fits_the_planners_hbm_model(topo, layout, inverse, chunks, variant):
+    """The pencil program at the grid cell's size (32768² complex64 over a
+    v5e 2x2) compiles with one all-to-all per slab, and its per-chip bytes
+    (argument, output and temporaries) stay inside the working set the
+    planner's HBM gate assumes for every jnp engine's pencil rung, from one
+    slab to the planner's largest slab count (16)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core import distributed
+    from repro.engines import builtin
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n = 32768
+    x = jax.ShapeDtypeStruct((n, n), jnp.complex64,
+                             sharding=distributed.pencil_sharding(mesh, "data", layout))
+    program = distributed.repro_pencil_ifft2 if inverse else distributed.repro_pencil_fft2
+    compiled = program.lower(x, mesh=mesh, axis="data", layout=layout, variant=variant,
+                             chunks=chunks).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"= \S+ all-to-all\(", text)) == 2 * chunks  # re and im parts
+    mem = compiled.memory_analysis()
+    block = 8 * n * n // 4
+    assert mem.argument_size_in_bytes == mem.output_size_in_bytes == block
+    per_chip = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert per_chip <= builtin._PENCIL_BLOCKS * block

@@ -209,9 +209,9 @@ def test_nested_front_door_calls_share_the_outer_call():
 
 
 def test_plan_resolve_is_a_timed_span_with_its_fields():
-    fields = {"entry", "kind", "shape", "dtype", "direction", "precision", "backend",
-              "mode", "outcome", "variant", "plan_mode", "est_time_s", "measured_us",
-              "degrade_reason", "cache_path", "key"}
+    fields = {"entry", "kind", "shape", "dtype", "direction", "n_devices", "layout",
+              "precision", "backend", "mode", "outcome", "variant", "plan_mode",
+              "est_time_s", "measured_us", "degrade_reason", "cache_path", "key"}
     cache = PlanCache()
     with obs.capture(profile=True) as trace:
         resolve_call("fft2d", (4, 32, 32), cache=cache)
