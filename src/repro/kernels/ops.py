@@ -16,18 +16,25 @@ stage count and the twiddle transcendentals. 2D entry points fail over to an
 unfused row/column composition when the frame's true working set exceeds the
 VMEM budget (``fft2_fits_vmem``) instead of overflowing it.
 
-``fft2_kernel`` and ``rfft2_kernel`` dispatch their stages separately, each
-under a ``repro.obs`` span: ``kernel.launch`` (the fused Pallas call) and
-``kernel.assemble`` (the complex result from the kernel's re/im planes), or
-on the failover ``fft.rows`` and ``fft.columns``.
+Each entry point's fused branch is one compiled program with a stable name
+(``repro_rfft2_kernel``, ...): the input cast or re/im split, the fused
+Pallas call and the complex64 result built from its re/im planes, so no
+eager program splits or assembles a complex array around the kernel.
+``fft2_kernel`` and ``rfft2_kernel`` dispatch it under a ``repro.obs`` span
+``kernel.launch``; their failover dispatches its stages separately, under
+``fft.rows`` and ``fft.columns`` (and ``fft2_kernel``'s complex result
+after them under ``kernel.assemble``).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
 from repro.core.fft1d import bit_reversal_permutation
@@ -136,15 +143,39 @@ def _flatten_rows(x: jax.Array):
     return x.reshape(flat, n), lead
 
 
-def fft_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
-    """Fused-kernel FFT along the last axis (any leading batch dims)."""
-    interpret = _interpret_default() if interpret is None else interpret
+_program = functools.partial(jax.jit, static_argnames=("radix", "interpret"))
+
+
+def _half_spectrum(yr: jax.Array, yi: jax.Array) -> jax.Array:
+    """complex64 from a real-input kernel's (..., N/2+1) re/im planes.
+
+    The kernel writes each plane with its odd last axis padded to whole
+    128-lane vregs. The TPU lays the complex64 result out with that axis
+    major, padding-free (for batches of whole sublane tiles), and its
+    ``X64Combine`` writes in the layout of its operands: so move the
+    planes to that layout first, and the combine writes the result in
+    place. Left to the compiler, the combine runs on the padded planes
+    and the complex64 array is copied after it, a third more bytes and
+    one more pass (measured on a v5e at 2048x512x512).
+    """
+    major = Layout(major_to_minor=(yr.ndim - 1, *range(yr.ndim - 1)))
+    return lax.complex(with_layout_constraint(yr, major), with_layout_constraint(yi, major))
+
+
+@_program
+def repro_fft_kernel(x: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """:func:`fft_kernel` as one program: split, fused kernel, complex64."""
     re, im = _split(x)
     re2, lead = _flatten_rows(re)
     im2, _ = _flatten_rows(im)
     yr, yi = fft_fused(re2, im2, radix=radix, interpret=interpret)
-    y = yr + 1j * yi
-    return y.reshape(*lead, x.shape[-1])
+    return lax.complex(yr, yi).reshape(*lead, x.shape[-1])
+
+
+def fft_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
+    """Fused-kernel FFT along the last axis (any leading batch dims)."""
+    interpret = _interpret_default() if interpret is None else interpret
+    return repro_fft_kernel(x, radix=radix, interpret=interpret)
 
 
 def fft_staged(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
@@ -188,21 +219,32 @@ def _fft_rows(re: jax.Array, im: jax.Array, *, radix: int, interpret: bool):
     return jnp.real(z).astype(jnp.float32), jnp.imag(z).astype(jnp.float32)
 
 
+@_program
+def repro_fft2_kernel(x: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """The fused branch of :func:`fft2_kernel` as one program."""
+    re, im = _split(x)
+    f, h, w, lead = _frames(x)
+    yr, yi = fft2_fused(re.reshape(f, h, w), im.reshape(f, h, w), radix=radix,
+                        interpret=interpret)
+    return lax.complex(yr, yi).reshape(*lead, h, w)
+
+
 def fft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
     """Fused-kernel 2D FFT of (..., H, W); unfused failover for big frames."""
     interpret = _interpret_default() if interpret is None else interpret
-    re, im = _split(x)
+    x = jnp.asarray(x)
     f, h, w, lead = _frames(x)
-    re, im = re.reshape(f, h, w), im.reshape(f, h, w)
     if fft2_fits_vmem(h, w) and not _faults.vmem_exhausted(
         "kernel.fused", kind="fft2d", h=h, w=w
     ):
         with obs.span("kernel.launch", kernel="repro_fft2_fused"):
-            yr, yi = fft2_fused(re, im, radix=radix, interpret=interpret)
+            return repro_fft2_kernel(x, radix=radix, interpret=interpret)
     else:
         # Frame working set exceeds VMEM: row pass, materialised corner
         # turn, column pass — more HBM trips, but never an overflow.
         _failover_event("fft2d", h, w, f, real=False)
+        re, im = _split(x)
+        re, im = re.reshape(f, h, w), im.reshape(f, h, w)
         with obs.span("fft.rows"):
             yr, yi = _fft_rows(re.reshape(f * h, w), im.reshape(f * h, w),
                                radix=radix, interpret=interpret)
@@ -216,18 +258,23 @@ def fft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) 
         return (yr + 1j * yi).reshape(*lead, h, w)
 
 
+@_program
+def repro_rfft_kernel(x: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """:func:`rfft_kernel` as one program: cast, fused kernel, complex64."""
+    re, lead = _flatten_rows(x.astype(jnp.float32))
+    yr, yi = rfft_fused(re, radix=radix, interpret=interpret)
+    return _half_spectrum(yr, yi).reshape(*lead, x.shape[-1] // 2 + 1)
+
+
 def rfft_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
     """Real-input fused FFT along the last axis -> (..., N/2+1) complex."""
     interpret = _interpret_default() if interpret is None else interpret
-    x = jnp.asarray(x)
-    re, lead = _flatten_rows(x.astype(jnp.float32))
-    yr, yi = rfft_fused(re, radix=radix, interpret=interpret)
-    return (yr + 1j * yi).reshape(*lead, x.shape[-1] // 2 + 1)
+    return repro_rfft_kernel(x, radix=radix, interpret=interpret)
 
 
-def irfft_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
-    """Inverse of :func:`rfft_kernel`: (..., N/2+1) complex -> real (..., N)."""
-    interpret = _interpret_default() if interpret is None else interpret
+@_program
+def repro_irfft_kernel(y: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """:func:`irfft_kernel` as one program: split, fused kernel."""
     re, im = _split(y)
     re2, lead = _flatten_rows(re)
     im2, _ = _flatten_rows(im)
@@ -235,17 +282,31 @@ def irfft_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None)
     return out.reshape(*lead, out.shape[-1])
 
 
+def irfft_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
+    """Inverse of :func:`rfft_kernel`: (..., N/2+1) complex -> real (..., N)."""
+    interpret = _interpret_default() if interpret is None else interpret
+    return repro_irfft_kernel(y, radix=radix, interpret=interpret)
+
+
+@_program
+def repro_rfft2_kernel(x: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """The fused branch of :func:`rfft2_kernel` as one program."""
+    f, h, w, lead = _frames(x)
+    yr, yi = rfft2_fused(x.astype(jnp.float32).reshape(f, h, w), radix=radix,
+                         interpret=interpret)
+    return _half_spectrum(yr, yi).reshape(*lead, h, w // 2 + 1)
+
+
 def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
     """Real-input fused 2D FFT of (..., H, W) -> (..., H, W/2+1) complex."""
     interpret = _interpret_default() if interpret is None else interpret
-    x = jnp.asarray(x).astype(jnp.float32)
+    x = jnp.asarray(x)
     f, h, w, lead = _frames(x)
-    xf = x.reshape(f, h, w)
     if fft2_fits_vmem(h, w, arrays=_REAL2D_ARRAYS) and not _faults.vmem_exhausted(
         "kernel.fused", kind="rfft2d", h=h, w=w
     ):
         with obs.span("kernel.launch", kernel="repro_rfft2_fused"):
-            yr, yi = rfft2_fused(xf, radix=radix, interpret=interpret)
+            return repro_rfft2_kernel(x, radix=radix, interpret=interpret)
     else:
         # Unfused failover: row rfft kernel, corner turn in HBM, column FFT.
         # The column batch (f·(W/2+1) rows) is odd, which would force the
@@ -254,6 +315,7 @@ def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None)
         _failover_event("rfft2d", h, w, f, real=True)
         from repro.core.fft1d import fft_impl  # lazy: core imports kernels
 
+        xf = x.astype(jnp.float32).reshape(f, h, w)
         half = w // 2 + 1
         with obs.span("fft.rows"):
             if fft_fits_vmem(w):
@@ -269,25 +331,34 @@ def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None)
             z = fft_impl(z.swapaxes(-1, -2), variant=_jnp_variant(radix))
             z = z.swapaxes(-1, -2)
             return z.reshape(*lead, h, half)
-    with obs.span("kernel.assemble"):
-        return (yr + 1j * yi).reshape(*lead, h, w // 2 + 1)
+
+
+@_program
+def repro_irfft2_kernel(y: jax.Array, *, radix: int, interpret: bool) -> jax.Array:
+    """The fused branch of :func:`irfft2_kernel` as one program."""
+    re, im = _split(y)
+    f, h, half, lead = _frames(y)
+    out = irfft2_fused(re.reshape(f, h, half), im.reshape(f, h, half), radix=radix,
+                       interpret=interpret)
+    return out.reshape(*lead, h, 2 * (half - 1))
 
 
 def irfft2_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
     """Inverse of :func:`rfft2_kernel`: (..., H, W/2+1) -> real (..., H, W)."""
     interpret = _interpret_default() if interpret is None else interpret
-    re, im = _split(y)
+    y = jnp.asarray(y)
     f, h, half, lead = _frames(y)
     w = 2 * (half - 1)
-    re, im = re.reshape(f, h, half), im.reshape(f, h, half)
     if fft2_fits_vmem(h, w, arrays=_REAL2D_ARRAYS) and not _faults.vmem_exhausted(
         "kernel.fused", kind="irfft2d", h=h, w=w
     ):
-        out = irfft2_fused(re, im, radix=radix, interpret=interpret)
+        return repro_irfft2_kernel(y, radix=radix, interpret=interpret)
     else:
         # Column IFFT via the jnp engine (the odd f·(W/2+1) column batch
         # defeats the fused kernel's row tiling), then the fused row irfft.
         _failover_event("irfft2d", h, w, f, real=True)
+        re, im = _split(y)
+        re, im = re.reshape(f, h, half), im.reshape(f, h, half)
         from repro.core.fft1d import ifft_impl  # lazy: core imports kernels
 
         z = ifft_impl((re + 1j * im).swapaxes(-1, -2), variant=_jnp_variant(radix))

@@ -175,6 +175,28 @@ def test_compiled_kernel_keeps_its_name(one_chip):
                      text)
 
 
+def test_rfft2_program_assembles_its_result_in_place(one_chip, record_property):
+    """The fused rfft2 entry point compiles to one program: the kernel,
+    still named ``repro_rfft2_fused`` (the only ``repro_`` instruction, so
+    the pallas share reads only the kernel), one ``X64Combine`` (the
+    complex64 write), which is the program's result (no copy of the
+    complex64 array after it), and one complex64 half spectrum out."""
+    from repro.kernels.ops import repro_rfft2_kernel
+
+    f, h, w = 8, 512, 512
+    compiled = repro_rfft2_kernel.lower(_f32((f, h, w), one_chip), radix=4,
+                                        interpret=False).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    named = re.findall(r"%(repro_\w+?)(?:\.\d+)? = ", entry)
+    assert named == ["repro_rfft2_fused"]
+    assert entry.count('custom_call_target="X64Combine"') == 1
+    assert re.search(r'ROOT %\S+ = c64\S+ custom-call\(.*custom_call_target="X64Combine"', entry)
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == f * h * (w // 2 + 1) * 8
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("variant", ["looped", "unrolled", "stockham", "radix4"])
 @pytest.mark.parametrize("layout,inverse,chunks",
                          [("rows", True, 1), ("cols", False, 4), ("rows", False, 16)])
