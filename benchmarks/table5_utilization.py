@@ -16,15 +16,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+import repro.xfft as xfft
 from benchmarks.common import emit
-from repro.core.fft1d import fft_impl
 from repro.kernels.ops import hbm_traffic_model
 
 
 def _compiled_stats(variant: str, n: int, batch: int = 64):
-    fn = jax.jit(lambda x: fft_impl(x, variant=variant))
     x = jax.ShapeDtypeStruct((batch, n), jnp.complex64)
-    compiled = fn.lower(x).compile()
+    with xfft.config(variant=variant):
+        compiled = jax.jit(xfft.fft).lower(x).compile()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     n_instr = sum(

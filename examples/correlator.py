@@ -6,7 +6,7 @@ a noisy scene entirely in the Fourier domain:
   correlation = IFFT2( FFT2(scene) · conj(FFT2(template)) )
 
 Scene and template are REAL, so the whole pipeline runs through the
-two-for-one ``rfft2``/``irfft2`` path (``repro.core.correlate2``): the
+two-for-one ``rfft2``/``irfft2`` path (``repro.spectral.correlate2``): the
 conjugate-symmetric half spectrum carries all the information — half the
 arithmetic and HBM traffic of the complex transform, same peak.
 
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.xfft as xfft
-from repro.core import correlate2
+from repro.spectral import correlate2
 from repro.compile_cache import enable_compile_cache
 
 

@@ -12,9 +12,9 @@ import numpy as np
 
 import repro.xfft as xfft
 from repro.core import butterfly_counts
-from repro.core.fft2d import fft2_stream
 from repro.kernels import fft2_kernel, fft_kernel, hbm_traffic_model
 from repro.compile_cache import enable_compile_cache
+from repro.plan import execute, resolve
 
 
 def main():
@@ -45,9 +45,10 @@ def main():
           bool(np.allclose(np.asarray(half), np.fft.rfft2(img, norm="ortho"),
                            atol=1e-3)))
 
-    # 3. Streaming frames through the ping-pong pipeline (paper fig. 3)
+    # 3. Streaming frames through the ping-pong pipeline (paper fig. 3),
+    #    planned like every other problem (engine and scan unroll).
     frames = rng.standard_normal((6, 32, 32)).astype(np.float32)
-    outs = fft2_stream(jnp.asarray(frames))
+    outs = execute(resolve("fft2d_stream", frames.shape), jnp.asarray(frames))
     print("stream matches per-frame:",
           bool(np.allclose(np.asarray(outs), np.fft.fft2(frames), atol=1e-3)))
 

@@ -16,8 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.xfft as xfft
-from repro.core.fft2d import fft2_stream
-from repro.plan import default_cache, plan_fft
+from repro.plan import default_cache, execute, plan_fft
 from repro.compile_cache import enable_compile_cache
 
 
@@ -46,8 +45,8 @@ def main():
     )
     args = ap.parse_args()
 
-    # Warm the plan cache before serving: tune once for the request shape so
-    # every variant="auto" resolution below is a cache hit, never a re-tune.
+    # Warm the plan cache before serving: tune once for the request shape,
+    # then serve every batch on that plan (engine and scan unroll).
     t_plan = time.time()
     plan = plan_fft(
         "fft2d_stream", (args.batch, args.hw, args.hw), mode=args.plan_mode
@@ -65,7 +64,7 @@ def main():
             start = json.load(f)["next_frame"]
         print(f"[service] resuming at frame {start}")
 
-    pipeline = jax.jit(lambda f: fft2_stream(f, variant="auto", unroll="auto"))
+    pipeline = jax.jit(lambda f: execute(plan, f))
     served = 0
     t0 = time.time()
     checks = []

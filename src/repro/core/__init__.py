@@ -1,34 +1,21 @@
-"""Core: the paper's area-efficient FFT engine and its applications."""
+"""Core: the paper's area-efficient FFT engine as plain transform bodies.
+
+The jnp engines' pass programs, their 2D compositions, the ping-pong
+stream and the pencil program, each under a concrete variant. Nothing
+here plans or picks an engine.
+"""
 
 from repro.core.fft1d import (
     bit_reversal_permutation,
     butterfly_counts,
-    fft,
     fft_routing_tables,
-    ifft,
 )
-from repro.core.fft2d import fft2, fft2_stream, fftshift2, ifft2, ifftshift2
-from repro.core.rfft import irfft, irfft2, rfft, rfft2
-from repro.core.spectral import correlate2, fftconv, fourier_mixing, log_mel, stft
+from repro.core.fft2d import fftshift2, ifftshift2
 
 __all__ = [
     "bit_reversal_permutation",
     "butterfly_counts",
-    "fft",
     "fft_routing_tables",
-    "ifft",
-    "fft2",
-    "fft2_stream",
     "fftshift2",
     "ifftshift2",
-    "ifft2",
-    "rfft",
-    "irfft",
-    "rfft2",
-    "irfft2",
-    "correlate2",
-    "fftconv",
-    "fourier_mixing",
-    "log_mel",
-    "stft",
 ]

@@ -133,7 +133,7 @@ def repro_pencil_fft2(x, mesh, axis, layout, variant, chunks):
     data dependency on slab i-1's column FFT, so the scheduler can overlap
     collective i with compute i-1 (the ping-pong insight applied to the
     collective itself). The planner picks ``chunks`` for a sharded
-    ``repro.xfft`` call; call this directly only to pin it."""
+    front-door call; call this directly only to pin it."""
     return _pencil_program(x, mesh, axis, layout, variant, chunks, inverse=False)
 
 

@@ -19,19 +19,16 @@ JAX mapping (see DESIGN.md §2):
   * ``variant="radix4"``   — radix-4 Stockham: half the stage count and
     half the twiddle transcendentals (one radix-2 stage when log2(N) is
     odd) — the software analogue of the higher-radix butterfly papers.
-  * ``variant="fused"`` / ``"fused_r4"`` — the Pallas kernels
-    (``repro.kernels``): the whole transform in one VMEM residency, one
-    HBM round trip; ``fused_r4`` runs the radix-4 panel inside.
 
 All variants compute the same DFT and are tested against each other and a
-float64 DFT oracle. On the jnp variants (``looped`` to ``radix4``) each 1D
-pass, the cast, the axis moves and (inverse) the conjugations and scaling
-included, is one compiled program per shape (``repro_jnp_fft_pass``).
+float64 DFT oracle. Each 1D pass, the cast, the axis moves and (inverse)
+the conjugations and scaling included, is one compiled program per shape
+(``repro_jnp_fft_pass``).
 
-Public transform calls belong to ``repro.xfft`` (plan-backed dispatch, no
-per-call variant kwargs); this module keeps the engines themselves
-(``fft_impl``/``ifft_impl`` plus the per-variant bodies) and warn-once
-deprecation shims under the old names.
+This module is a library of transform bodies: it takes a concrete variant
+and knows nothing of planning or of the engine registry, which binds each
+variant to its engine name. The fused Pallas kernels (engines ``fused``
+and ``fused_r4``) live in ``repro.kernels``.
 """
 
 from __future__ import annotations
@@ -44,21 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core._deprecation import warn_deprecated
-
-Variant = Literal[
-    "looped", "unrolled", "stockham", "radix4", "fused", "fused_r4", "auto"
-]
-
-#: Variants this module's dispatch chains terminate on. Any OTHER name is
-#: looked up in the ``repro.engines`` registry and delegated wholesale to
-#: that engine's executor (before any complex64 cast — a registered engine
-#: owns its own dtype policy, e.g. ``reference_x64`` computes in c128).
-BUILTIN_VARIANTS = ("looped", "unrolled", "stockham", "radix4", "fused", "fused_r4")
+Variant = Literal["looped", "unrolled", "stockham", "radix4"]
 
 __all__ = [
-    "fft",
-    "ifft",
+    "fft_impl",
+    "ifft_impl",
     "fft_routing_tables",
     "bit_reversal_permutation",
     "butterfly_counts",
@@ -68,9 +55,9 @@ __all__ = [
 def _check_pow2(n: int, axis: Optional[int] = None) -> int:
     """log2(n), or a ValueError that names the offending axis and size.
 
-    The one pow2 error contract for the whole stack: ``repro.xfft`` and
-    the engine entries both validate through here, so the message (the
-    ISSUE-3 satellite wording) can never drift between layers.
+    The one pow2 error contract for the whole stack: the front door and
+    the transform bodies both validate through here, so the message can
+    never drift between layers.
     """
     if n < 2 or (n & (n - 1)) != 0:
         if axis is not None:
@@ -80,6 +67,13 @@ def _check_pow2(n: int, axis: Optional[int] = None) -> int:
             )
         raise ValueError(f"radix-2 FFT needs a power-of-two length, got {n}")
     return int(math.log2(n))
+
+
+def _next_pow2(n: int) -> int:
+    """Power-of-two cover of ``n``, floored at 2 (the engines' minimum
+    transform length). Shared by fftconv, the imaging tiled-convolution
+    padding and the planner's oaconv2d tile sweep."""
+    return max(2, 1 << max(int(n) - 1, 0).bit_length())
 
 
 def canonical_axis(axis: int, ndim: int, name: str = "fft") -> int:
@@ -311,92 +305,17 @@ def repro_jnp_fft_pass(
     return jnp.moveaxis(y, -1, axis)
 
 
-def fft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
-    """Radix-2 FFT along ``axis``. Input real or complex; returns complex64.
-
-    This is the engine entry the xfft front door and the planner dispatch
-    to; ``variant="auto"`` resolves the schedule through ``repro.plan``
-    (cached MEASURE plan if one was tuned for this shape, analytic
-    ESTIMATE else, scoped ``repro.xfft.config`` overrides applied).
-    """
-    orig = x
+def fft_impl(x: jax.Array, axis: int = -1, *, variant: Variant) -> jax.Array:
+    """FFT along ``axis`` on jnp engine ``variant``: real or complex in,
+    complex64 out, one pass program."""
     x = jnp.asarray(x)
-    user_axis = axis
-    axis = canonical_axis(axis, x.ndim)
-    _check_pow2(x.shape[axis], axis=user_axis)
-    if variant == "auto":
-        from repro.plan.api import resolve  # lazy: plan imports core
-
-        key_shape = x.shape[:axis] + x.shape[axis + 1:] + (x.shape[axis],)
-        variant = resolve("fft1d", key_shape).variant
-    if variant not in BUILTIN_VARIANTS:
-        # Registry fallback gets the caller's ORIGINAL array: the engine
-        # owns every jnp touch (an x64 engine must asarray/moveaxis inside
-        # its enable_x64 scope or 64-bit input is truncated to 32).
-        from repro.engines import apply_engine
-
-        return apply_engine(variant, "fft1d", orig, axis=axis)
-    if variant in _JNP_BODIES:
-        return repro_jnp_fft_pass(x, axis=axis, variant=variant, inverse=False)
-    # fused / fused_r4
-    from repro.kernels.ops import fft_kernel  # lazy: kernels import core
-
-    x = jnp.moveaxis(x.astype(jnp.complex64), axis, -1)
-    y = fft_kernel(x, radix=4 if variant == "fused_r4" else 2)
-    return jnp.moveaxis(y, -1, axis)
+    return repro_jnp_fft_pass(x, axis=canonical_axis(axis, x.ndim), variant=variant,
+                              inverse=False)
 
 
-def ifft_impl(x: jax.Array, axis: int = -1, variant: Variant = "auto") -> jax.Array:
-    """Inverse FFT via the conjugation identity (shares the forward engine)."""
-    orig = x
+def ifft_impl(x: jax.Array, axis: int = -1, *, variant: Variant) -> jax.Array:
+    """Inverse FFT along ``axis`` (1/N) on jnp engine ``variant``, one pass
+    program."""
     x = jnp.asarray(x)
-    axis_n = canonical_axis(axis, x.ndim)
-    n = x.shape[axis_n]
-    if variant == "auto":
-        from repro.plan.api import resolve  # lazy: plan imports core
-
-        # Inverse transforms carry their own plan direction so forward
-        # tuning never cross-contaminates them. Key on the axis-moved
-        # shape (transform axis last), matching the forward convention.
-        key_shape = x.shape[:axis_n] + x.shape[axis_n + 1:] + (n,)
-        variant = resolve("fft1d", key_shape, direction="inv").variant
-    if variant not in BUILTIN_VARIANTS:
-        from repro.engines import apply_engine  # lazy: registry fallback
-
-        return apply_engine(variant, "fft1d", orig, direction="inv", axis=axis_n)
-    if variant in _JNP_BODIES:
-        _check_pow2(n, axis=axis)
-        return repro_jnp_fft_pass(x, axis=axis_n, variant=variant, inverse=True)
-    x = x.astype(jnp.complex64)
-    return jnp.conj(fft_impl(jnp.conj(x), axis=axis, variant=variant)) / n
-
-
-def fft(
-    x: jax.Array, axis: int = -1, variant: Optional[Variant] = None
-) -> jax.Array:
-    """Deprecated alias of :func:`repro.xfft.fft` (kept for old call sites).
-
-    The per-call ``variant=`` kwarg is superseded by plan-backed dispatch:
-    ``None``/``"auto"`` lets ``repro.plan`` pick; a concrete variant is
-    honoured by scoping a ``repro.xfft.config`` override around the call.
-    """
-    warn_deprecated("repro.core.fft1d.fft", "repro.xfft.fft")
-    from repro import xfft  # lazy: xfft builds on this module
-
-    if variant is None or variant == "auto":
-        return xfft.fft(x, axis=axis)
-    with xfft.config(variant=variant):
-        return xfft.fft(x, axis=axis)
-
-
-def ifft(
-    x: jax.Array, axis: int = -1, variant: Optional[Variant] = None
-) -> jax.Array:
-    """Deprecated alias of :func:`repro.xfft.ifft` (kept for old call sites)."""
-    warn_deprecated("repro.core.fft1d.ifft", "repro.xfft.ifft")
-    from repro import xfft  # lazy: xfft builds on this module
-
-    if variant is None or variant == "auto":
-        return xfft.ifft(x, axis=axis)
-    with xfft.config(variant=variant):
-        return xfft.ifft(x, axis=axis)
+    return repro_jnp_fft_pass(x, axis=canonical_axis(axis, x.ndim), variant=variant,
+                              inverse=True)

@@ -15,81 +15,26 @@ buffer rotation is the scan carry.
 
 from __future__ import annotations
 
-from typing import Literal, Optional, Union
-
 import jax
 import jax.numpy as jnp
 
-from repro.core._deprecation import warn_deprecated
-from repro.core.fft1d import BUILTIN_VARIANTS, Variant, fft_impl, ifft_impl
+from repro.core.fft1d import Variant, fft_impl, ifft_impl
 
-__all__ = ["fft2", "ifft2", "fft2_stream", "fftshift2", "ifftshift2"]
-
-
-def _resolve_2d(kind: str, shape, variant: Variant, direction: str = "fwd") -> Variant:
-    """Map ``variant="auto"`` to a concrete schedule for the whole 2D problem
-    (one plan per frame shape, not one per 1D pass)."""
-    if variant != "auto":
-        return variant
-    from repro.plan.api import resolve  # lazy: plan imports core
-
-    return resolve(kind, tuple(shape), direction=direction).variant
+__all__ = ["fft2_impl", "ifft2_impl", "fft2_stream", "fftshift2", "ifftshift2"]
 
 
-def fft2_impl(x: jax.Array, variant: Variant = "auto") -> jax.Array:
-    """2D FFT over the last two axes: row pass then column pass (paper fig. 1)."""
-    variant = _resolve_2d("fft2d", jnp.shape(x), variant)
-    if variant in ("fused", "fused_r4"):
-        from repro.kernels.ops import fft2_kernel  # lazy: kernels import core
-
-        # Whole-frame VMEM residency (with built-in failover to an unfused
-        # row/turn/column composition when the frame exceeds the budget).
-        return fft2_kernel(x, radix=4 if variant == "fused_r4" else 2)
-    if variant not in BUILTIN_VARIANTS:
-        # The engine owns every jnp touch (see repro.engines.apply_engine).
-        from repro.engines import apply_engine
-
-        return apply_engine(variant, "fft2d", x)
-    y = fft_impl(x, axis=-1, variant=variant)   # first 1D FFT block (rows)
+def fft2_impl(x: jax.Array, *, variant: Variant) -> jax.Array:
+    """2D FFT over the last two axes on jnp engine ``variant``: row pass
+    then column pass (paper fig. 1), one pass program each."""
+    y = fft_impl(x, axis=-1, variant=variant)     # first 1D FFT block (rows)
     return fft_impl(y, axis=-2, variant=variant)  # second 1D FFT block (columns)
 
 
-def ifft2_impl(x: jax.Array, variant: Variant = "auto") -> jax.Array:
-    # Inverse transforms plan under their own direction key ("inv") so
-    # forward-tuned wisdom never cross-contaminates them.
-    variant = _resolve_2d("fft2d", jnp.shape(x), variant, direction="inv")
-    if variant in ("fused", "fused_r4"):
-        x = jnp.asarray(x)
-        h, w = x.shape[-2], x.shape[-1]
-        return jnp.conj(fft2_impl(jnp.conj(x), variant=variant)) / (h * w)
-    if variant not in BUILTIN_VARIANTS:
-        from repro.engines import apply_engine  # lazy: registry fallback
-
-        return apply_engine(variant, "fft2d", x, direction="inv")
+def ifft2_impl(x: jax.Array, *, variant: Variant) -> jax.Array:
+    """Inverse 2D FFT over the last two axes (1/(H·W)) on jnp engine
+    ``variant``."""
     y = ifft_impl(x, axis=-1, variant=variant)
     return ifft_impl(y, axis=-2, variant=variant)
-
-
-def fft2(x: jax.Array, variant: Optional[Variant] = None) -> jax.Array:
-    """Deprecated alias of :func:`repro.xfft.fft2` (kept for old call sites)."""
-    warn_deprecated("repro.core.fft2d.fft2", "repro.xfft.fft2")
-    from repro import xfft  # lazy: xfft builds on this module
-
-    if variant is None or variant == "auto":
-        return xfft.fft2(x)
-    with xfft.config(variant=variant):
-        return xfft.fft2(x)
-
-
-def ifft2(x: jax.Array, variant: Optional[Variant] = None) -> jax.Array:
-    """Deprecated alias of :func:`repro.xfft.ifft2` (kept for old call sites)."""
-    warn_deprecated("repro.core.fft2d.ifft2", "repro.xfft.ifft2")
-    from repro import xfft  # lazy: xfft builds on this module
-
-    if variant is None or variant == "auto":
-        return xfft.ifft2(x)
-    with xfft.config(variant=variant):
-        return xfft.ifft2(x)
 
 
 def fftshift2(x: jax.Array) -> jax.Array:
@@ -110,37 +55,17 @@ def ifftshift2(x: jax.Array) -> jax.Array:
     )
 
 
-def fft2_stream(
-    frames: jax.Array,
-    variant: Variant = "auto",
-    unroll: Union[int, Literal["auto"]] = 1,
-) -> jax.Array:
+def fft2_stream(frames: jax.Array, *, variant: Variant, unroll: int = 1) -> jax.Array:
     """Streaming 2D FFT over ``frames[t, H, W]`` with ping-pong double buffering.
 
     Frame t's row pass and frame t−1's column pass execute in the same scan
     step (two concurrent engines). Output t is the 2D FFT of frame t — the
     one-frame pipeline latency is internal: a zero frame is fed through to
-    drain the pipe, matching the hardware's drain cycle.
-
-    ``variant="auto"`` / ``unroll="auto"`` resolve through ``repro.plan``
-    with the stream's own problem key (the scan unroll is part of the plan).
+    drain the pipe, matching the hardware's drain cycle. ``unroll`` is the
+    scan's unroll factor (a planned knob of the ``fft2d_stream`` kind).
     """
     if frames.ndim < 3:
         raise ValueError("fft2_stream expects (T, H, W) or (T, ..., H, W)")
-    if variant == "auto" or unroll == "auto":
-        from repro.plan.api import resolve  # lazy: plan imports core
-
-        plan = resolve("fft2d_stream", tuple(frames.shape))
-        if variant == "auto":
-            variant = plan.variant
-        if unroll == "auto":
-            unroll = plan.unroll
-    if variant not in BUILTIN_VARIANTS:
-        # A registered engine (e.g. reference_x64) runs its own stream op
-        # — the scan carry must share the engine's compute dtype.
-        from repro.engines import apply_engine
-
-        return apply_engine(variant, "fft2d_stream", frames)
     if not jnp.issubdtype(frames.dtype, jnp.complexfloating):
         frames = frames.astype(jnp.complex64)
 

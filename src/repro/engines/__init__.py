@@ -7,7 +7,9 @@ fused Pallas kernels, the double-precision ``reference_x64`` backend, and
 any third-party registration — is an :class:`EngineSpec` describing what
 it can do (kinds × precisions × backend × VMEM needs) and how to run it.
 The planner enumerates the registry by capability; adding a backend or a
-precision is a registration, not a planner edit.
+precision is a registration, not a planner edit. This package is also the
+one place an engine name becomes code: every dispatcher runs an engine
+through :func:`apply_engine` or its spec's op.
 
     from repro.engines import iter_engines, get_engine, engine, CostHints
 
@@ -16,6 +18,8 @@ precision is a registration, not a planner edit.
 
 Importing this package registers the built-in engines.
 """
+
+import inspect
 
 from repro.engines.registry import (
     PRECISIONS,
@@ -44,6 +48,7 @@ __all__ = [
     "get_engine",
     "has_engine",
     "iter_engines",
+    "op_takes",
     "register_engine",
     "registered_backends",
     "registered_variants",
@@ -51,29 +56,33 @@ __all__ = [
 ]
 
 
+def op_takes(op, name: str) -> bool:
+    """True when the executor ``op`` takes the keyword ``name``: ``axis``
+    (a 1D op that moves its transform axis inside its own program) or a
+    plan field (``unroll``, ``chunks``)."""
+    return name in inspect.signature(op).parameters
+
+
 def apply_engine(name: str, kind: str, x, *, direction: str = "fwd",
                  axis: int | None = None):
     """Run ``x`` through engine ``name``'s executor for ``(kind, direction)``.
 
-    This is the fallback the ``repro.core`` engine entries take for any
-    variant their builtin dispatch chains do not recognise — which is how
-    a registered engine (e.g. ``reference_x64``) serves every existing
-    call path (``repro.xfft``, ``repro.plan.execute``, MEASURE sweeps,
-    the serve layer) without those layers learning its name.
+    The one call form of dispatch: the front door, ``repro.plan.execute``
+    and MEASURE sweeps all run an engine through here (or through the
+    spec's op directly where they pass a plan field such as ``chunks``).
 
-    ``x`` must be the caller's ORIGINAL array: every jnp touch (asarray,
-    moveaxis, ...) happens in here, inside ``jax.enable_x64`` for engines
-    that require it — outside that scope jax re-canonicalizes 64-bit
-    dtypes down to 32 and a double input would be silently truncated
-    before the engine ever saw it. ``axis`` (1D kinds only) names the
-    transform axis; the executor itself always sees axes-last layout.
+    ``x`` is the caller's ORIGINAL array: every jnp touch happens in here,
+    inside ``jax.enable_x64`` for an engine that requires it — outside that
+    scope jax re-canonicalizes 64-bit dtypes down to 32 and a double input
+    would be truncated before the engine saw it. ``axis`` (1D kinds only)
+    names the transform axis: an op that takes ``axis`` gets it; any other
+    op sees the axis moved last.
 
     The ``engine.apply`` dispatch span is NOT emitted here: it lives in
-    :func:`repro.resilience.ladder.run_plan`, which wraps every planned
-    and forced dispatch for *all* engines (builtin chains included) and
-    feeds the calibration ledger observed durations. Emitting here too
-    would double-count registry engines — and MEASURE sweeps, which call
-    executors directly, must stay out of the observed population anyway.
+    :func:`repro.resilience.ladder.run_plan`, which wraps every planned and
+    forced dispatch and feeds the calibration ledger observed durations;
+    MEASURE sweeps, which also call through here, stay out of that
+    population.
     """
     spec = get_engine(name)
     fn = spec.op(kind, direction)
@@ -82,11 +91,14 @@ def apply_engine(name: str, kind: str, x, *, direction: str = "fwd",
         import jax.numpy as jnp
 
         arr = jnp.asarray(x)
-        if axis is not None and kind in ("fft1d", "rfft1d"):
-            ax = axis % arr.ndim
-            if ax != arr.ndim - 1:
-                return jnp.moveaxis(fn(jnp.moveaxis(arr, ax, -1)), -1, ax)
-        return fn(arr)
+        if axis is None or kind not in ("fft1d", "rfft1d"):
+            return fn(arr)
+        ax = axis % arr.ndim
+        if op_takes(fn, "axis"):
+            return fn(arr, axis=ax)
+        if ax == arr.ndim - 1:
+            return fn(arr)
+        return jnp.moveaxis(fn(jnp.moveaxis(arr, ax, -1)), -1, ax)
 
     if spec.requires_x64:
         from repro._x64 import enable_x64

@@ -1,10 +1,11 @@
 """The six seed engines, registered with their true capability envelopes.
 
-The engine *bodies* keep living where they grew — the jnp schedules in
-``repro.core`` and the fused Pallas kernels in ``repro.kernels`` — but
-the planner no longer knows their names: everything it used to hardcode
-(the ``PLAN_VARIANTS`` tuple, the fused-kind/device/VMEM gating in
-``variant_candidates``, the per-variant cost tables in ``autotune``) now
+This module is where a builtin engine's name becomes code. The bodies
+live below it: the jnp schedules in ``repro.core`` (pass programs, the 2D
+compositions, the ping-pong stream, the pencil program) and the fused
+Pallas kernels in ``repro.kernels``. Each engine's op factory binds its
+variant or radix to those bodies; everything the planner used to
+hardcode (the fused-kind/device/VMEM gating, the per-variant cost tables)
 reads off these specs.
 
 Capability parity with the pre-registry planner is deliberate and tested:
@@ -14,8 +15,7 @@ Capability parity with the pre-registry planner is deliberate and tested:
   the pencil program's per-chip working set fits the chip's HBM;
 * the fused kernels serve the 1D/2D complex+real kinds only, single
   device, power-of-two dims, and only while a 1D row tile fits the VMEM
-  budget (``working_set``) — the exact gate ``variant_candidates`` used
-  to open-code.
+  budget (``working_set``).
 """
 
 from __future__ import annotations
@@ -23,7 +23,12 @@ from __future__ import annotations
 import functools
 import math
 
+import jax.numpy as jnp
+
+from repro.core import fft1d, fft2d, rfft
+from repro.core.distributed import pencil_fft2
 from repro.engines.registry import CostHints, EngineSpec, register_engine
+from repro.resilience import faults
 
 #: Every planner kind: the jnp schedules are the universal fallback (the
 #: stream/pencil/oaconv paths compose them per 1D pass).
@@ -35,43 +40,106 @@ _JNP_KINDS = (
 #: Kinds whose entry points dispatch to the fused Pallas kernels.
 _FUSED_KINDS = ("fft1d", "fft2d", "rfft1d", "rfft2d")
 
+#: (forward, inverse) core body per kind for the jnp engines; the pencil
+#: kind takes the plan's ``chunks`` and oaconv2d executes at the plan level
+#: (``repro.plan.execute``), not here.
+_JNP_BODIES = {
+    "fft1d": (fft1d.fft_impl, fft1d.ifft_impl),
+    "fft2d": (fft2d.fft2_impl, fft2d.ifft2_impl),
+    "rfft1d": (rfft.rfft_impl, rfft.irfft_impl),
+    "rfft2d": (rfft.rfft2_impl, rfft.irfft2_impl),
+    "fft2d_stream": (fft2d.fft2_stream, None),
+    "fft2d_pencil": (functools.partial(pencil_fft2, inverse=False),
+                     functools.partial(pencil_fft2, inverse=True)),
+}
 
-def _core_ops(name: str):
-    """Op factory shared by all builtin engines: the ``repro.core`` engine
-    entries under a concrete variant (their dispatch chains terminate on
-    builtin names, so this never re-enters the registry)."""
+
+def _jnp_ops(name: str):
+    """Op factory of a jnp engine: the core bodies under variant ``name``."""
 
     def factory(kind: str, direction: str):
-        inv = direction == "inv"
-        if kind == "fft1d":
-            from repro.core.fft1d import fft_impl, ifft_impl
+        body = _JNP_BODIES.get(kind, (None, None))[direction == "inv"]
+        return None if body is None else functools.partial(body, variant=name)
 
-            return functools.partial(ifft_impl if inv else fft_impl, variant=name)
-        if kind == "fft2d":
-            from repro.core.fft2d import fft2_impl, ifft2_impl
+    return factory
 
-            return functools.partial(ifft2_impl if inv else fft2_impl, variant=name)
-        if kind == "rfft1d":
-            from repro.core.rfft import irfft_impl, rfft_impl
 
-            return functools.partial(irfft_impl if inv else rfft_impl, variant=name)
-        if kind == "rfft2d":
-            from repro.core.rfft import irfft2_impl, rfft2_impl
+def _fused_fft1d(x, axis: int = -1, *, radix: int, inverse: bool):
+    """fft1d on the fused kernel: complex64 with the transform axis last;
+    the inverse by the conjugation identity."""
+    from repro.kernels.ops import fft_kernel  # lazy: pallas import
 
-            return functools.partial(irfft2_impl if inv else rfft2_impl, variant=name)
-        if kind == "fft2d_stream" and not inv:
-            from repro.core.fft2d import fft2_stream
+    x = jnp.moveaxis(x.astype(jnp.complex64), axis, -1)
+    if inverse:
+        y = jnp.conj(fft_kernel(jnp.conj(x), radix=radix)) / x.shape[-1]
+    else:
+        y = fft_kernel(x, radix=radix)
+    return jnp.moveaxis(y, -1, axis)
 
-            return functools.partial(fft2_stream, variant=name)
-        if kind == "fft2d_pencil":
-            from repro.core.distributed import pencil_fft2
 
-            # Takes the sharded grid and the plan's ``chunks``; the mesh,
-            # its axis and the layout are read from the grid's sharding.
-            return functools.partial(pencil_fft2, variant=name, inverse=inv)
-        # oaconv2d needs an (image, kernel) pair; it executes at the plan
-        # level (repro.plan.execute), not here.
-        return None
+def _fused_rfft1d(x, axis: int = -1, *, radix: int, inverse: bool):
+    """rfft1d on the fused kernels, with the transform axis moved last."""
+    from repro.kernels.ops import irfft_kernel, rfft_kernel  # lazy: pallas
+
+    if inverse:
+        x, kernel = x.astype(jnp.complex64), irfft_kernel
+    else:
+        x, kernel = rfft._ensure_real(x, "rfft").astype(jnp.float32), rfft_kernel
+    return jnp.moveaxis(kernel(jnp.moveaxis(x, axis, -1), radix=radix), -1, axis)
+
+
+def _fused_2d(x, *, kind: str, radix: int):
+    """One fused 2D kernel call; a ``vmem`` fault injected at the
+    ``kernel.fused`` seam sends a frame that fits to the unfused failover."""
+    from repro.kernels import ops  # lazy: pallas import
+
+    entry, unfused, real = {
+        "fft2d": (ops.fft2_kernel, ops.fft2_unfused, False),
+        "rfft2d": (ops.rfft2_kernel, ops.rfft2_unfused, True),
+        "irfft2d": (ops.irfft2_kernel, ops.irfft2_unfused, True),
+    }[kind]
+    h = x.shape[-2]
+    w = 2 * (x.shape[-1] - 1) if kind == "irfft2d" else x.shape[-1]
+    if ops.fft2_fits_budget(h, w, real=real) and faults.vmem_exhausted(
+        "kernel.fused", kind=kind, h=h, w=w
+    ):
+        return unfused(x, radix=radix)
+    return entry(x, radix=radix)
+
+
+def _fused_fft2d(x, *, radix: int, inverse: bool):
+    """fft2d on the fused kernel; the inverse by the conjugation identity."""
+    if not inverse:
+        return _fused_2d(x, kind="fft2d", radix=radix)
+    h, w = x.shape[-2], x.shape[-1]
+    return jnp.conj(_fused_2d(jnp.conj(x), kind="fft2d", radix=radix)) / (h * w)
+
+
+def _fused_rfft2d(x, *, radix: int, inverse: bool):
+    """rfft2d on the fused kernels: float32 in (forward), complex64 in
+    (inverse)."""
+    if inverse:
+        return _fused_2d(x.astype(jnp.complex64), kind="irfft2d", radix=radix)
+    x = rfft._ensure_real(x, "rfft2").astype(jnp.float32)
+    return _fused_2d(x, kind="rfft2d", radix=radix)
+
+
+_FUSED_BODIES = {
+    "fft1d": _fused_fft1d,
+    "fft2d": _fused_fft2d,
+    "rfft1d": _fused_rfft1d,
+    "rfft2d": _fused_rfft2d,
+}
+
+
+def _fused_ops(radix: int):
+    """Op factory of a fused engine: the Pallas kernels at ``radix``."""
+
+    def factory(kind: str, direction: str):
+        body = _FUSED_BODIES.get(kind)
+        if body is None:
+            return None
+        return functools.partial(body, radix=radix, inverse=direction == "inv")
 
     return factory
 
@@ -150,7 +218,7 @@ def _register_builtin_engines() -> None:
             kinds=_JNP_KINDS,
             radix=radix,
             cost=cost,
-            ops=_core_ops(name),
+            ops=_jnp_ops(name),
             predicate=_jnp_predicate,
             # stockham is the canonical always-works rung: pure jnp ops,
             # every kind, no VMEM cliff — the degradation ladder's bottom.
@@ -168,7 +236,7 @@ def _register_builtin_engines() -> None:
             predicate=_fused_predicate,
             cost=CostHints(traffic_factor=4.0, stage_overhead_s=0.8e-6,
                            flop_scale=flop_scale),
-            ops=_core_ops(name),
+            ops=_fused_ops(radix),
         ), _protect=True)
 
 

@@ -22,8 +22,8 @@ kernel lands as::
 and is immediately a planner candidate, a MEASURE sweep entrant, a
 ``benchmarks/fft_bench.py`` row and a ``tests/engines`` conformance case.
 
-This module imports nothing from the rest of the repo at module scope —
-``repro.plan``, ``repro.core`` and ``repro.xfft`` all build on it.
+This module imports nothing from the rest of the repo at module scope:
+``repro.plan`` and ``repro.xfft`` build on it.
 """
 
 from __future__ import annotations
@@ -158,10 +158,10 @@ class EngineSpec:
 
 _REGISTRY: Dict[str, EngineSpec] = {}
 
-#: Names whose execution is fused into the ``repro.core`` dispatch chains
-#: for speed (the six seed engines). Replacing or removing one would leave
-#: dispatch running the ORIGINAL body while the registry advertised the
-#: replacement — a silent lie — so registration refuses instead.
+#: The six seed engines. Persisted wisdom names them, the ladder's bottom
+#: rung is one of them, and the fused kernels' failovers run the stockham
+#: and radix4 pass programs directly: a replacement would change what
+#: those plans mean, so registration refuses instead.
 _PROTECTED: set = set()
 
 
@@ -173,9 +173,8 @@ def register_engine(
     Validates the declaration eagerly — a typo'd kind or precision should
     fail at registration, not at the first planning call — and rejects
     duplicate names unless ``replace=True``. The six seed engines cannot
-    be replaced at all: their bodies are fused into the core dispatch
-    chains, so an override would never execute — register under a new
-    name instead.
+    be replaced at all (see ``_PROTECTED``) — register under a new name
+    instead.
     """
     if not spec.name or not isinstance(spec.name, str):
         raise ValueError(f"engine name must be a non-empty string, got {spec.name!r}")
@@ -198,9 +197,8 @@ def register_engine(
     if spec.name in _REGISTRY:
         if spec.name in _PROTECTED:
             raise ValueError(
-                f"engine {spec.name!r} is a builtin fused into the core "
-                "dispatch chains and cannot be replaced; register your "
-                "engine under a new name"
+                f"engine {spec.name!r} is a builtin engine and cannot be "
+                "replaced; register your engine under a new name"
             )
         if not replace:
             raise ValueError(
@@ -215,8 +213,7 @@ def register_engine(
 
 def unregister_engine(name: str) -> None:
     """Remove an engine (plugin teardown / tests); unknown names are a
-    no-op. Builtin engines cannot be removed — core dispatch would keep
-    executing them while the planner denied they exist."""
+    no-op. Builtin engines cannot be removed (see ``_PROTECTED``)."""
     if name in _PROTECTED:
         raise ValueError(f"builtin engine {name!r} cannot be unregistered")
     _REGISTRY.pop(name, None)
@@ -270,8 +267,7 @@ def iter_engines(
 
 
 def registered_variants(precision: Optional[str] = None) -> Tuple[str, ...]:
-    """Engine names, optionally restricted to one precision (the
-    ``PLAN_VARIANTS`` deprecation alias derives from this)."""
+    """Engine names, optionally restricted to one precision."""
     return tuple(s.name for s in iter_engines(precision=precision))
 
 

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 import repro.xfft as xfft
-from repro.core.spectral import _is_real
+from repro.core.rfft import _is_real
 from repro.imaging.registration import hermitian_full
 
 # The ONE argument contract: axis canonicalization (bounds-checked, named

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 import repro.xfft as xfft
-from repro.core.spectral import _is_real
+from repro.core.rfft import _is_real
 
 __all__ = [
     "register_phase_correlation",

@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 import repro.xfft as xfft
-from repro.core.spectral import _is_real, _next_pow2
+from repro.core.fft1d import _next_pow2
+from repro.core.rfft import _is_real
 from repro.plan.api import resolve_call
 
 __all__ = ["oaconvolve2", "fftconv2", "matched_filter2"]
@@ -182,7 +183,7 @@ def matched_filter2(
 
     ``corr[i, j] = Σ scene[i+u, j+v]·conj(template[u, v])``, computed as
     an overlap-save convolution with the conjugate-flipped template, so
-    scenes far beyond :func:`repro.core.correlate2`'s equal-size,
+    scenes far beyond :func:`repro.spectral.correlate2`'s equal-size,
     single-transform contract still stream through VMEM-sized tiles.
     The peak of the result locates the template.
     """

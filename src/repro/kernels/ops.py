@@ -13,8 +13,10 @@ and any other backend is refused.
 
 All fused entry points take ``radix`` (2 or 4): radix-4 halves the in-VMEM
 stage count and the twiddle transcendentals. 2D entry points fail over to an
-unfused row/column composition when the frame's true working set exceeds the
-VMEM budget (``fft2_fits_vmem``) instead of overflowing it.
+unfused row/column composition (``fft2_unfused``, ``rfft2_unfused``,
+``irfft2_unfused``) when the frame's true working set exceeds the VMEM
+budget (``fft2_fits_vmem``) instead of overflowing it; a pass the fused
+kernel cannot tile runs the jnp engines' pass programs from ``repro.core``.
 
 Each entry point's fused branch is one compiled program with a stable name
 (``repro_rfft2_kernel``, ...): the input cast or re/im split, the fused
@@ -37,8 +39,8 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
-from repro.core.fft1d import bit_reversal_permutation
-from repro.resilience import faults as _faults
+from repro.core.fft1d import bit_reversal_permutation, repro_jnp_fft_pass
+from repro.core.rfft import repro_jnp_irfft_pass, repro_jnp_rfft_pass
 from repro.kernels.butterfly import butterfly_stage
 from repro.kernels.fft_radix2 import (
     _FFT2_WORKING_ARRAYS,
@@ -57,10 +59,13 @@ __all__ = [
     "fft_kernel",
     "fft_staged",
     "fft2_kernel",
+    "fft2_unfused",
     "rfft_kernel",
     "irfft_kernel",
     "rfft2_kernel",
+    "rfft2_unfused",
     "irfft2_kernel",
+    "irfft2_unfused",
     "hbm_traffic_model",
     "fft2_working_set",
     "fft2_fits_budget",
@@ -213,9 +218,8 @@ def _fft_rows(re: jax.Array, im: jax.Array, *, radix: int, interpret: bool):
     never overflows, whatever the frame geometry."""
     if fft_fits_vmem(re.shape[-1]):
         return fft_fused(re, im, radix=radix, interpret=interpret)
-    from repro.core.fft1d import fft_impl  # lazy: core imports kernels
-
-    z = fft_impl(re + 1j * im, variant=_jnp_variant(radix))
+    z = repro_jnp_fft_pass(re + 1j * im, axis=-1, variant=_jnp_variant(radix),
+                           inverse=False)
     return jnp.real(z).astype(jnp.float32), jnp.imag(z).astype(jnp.float32)
 
 
@@ -233,27 +237,31 @@ def fft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) 
     """Fused-kernel 2D FFT of (..., H, W); unfused failover for big frames."""
     interpret = _interpret_default() if interpret is None else interpret
     x = jnp.asarray(x)
+    if not fft2_fits_vmem(x.shape[-2], x.shape[-1]):
+        return fft2_unfused(x, radix=radix, interpret=interpret)
+    with obs.span("kernel.launch", kernel="repro_fft2_fused"):
+        return repro_fft2_kernel(x, radix=radix, interpret=interpret)
+
+
+def fft2_unfused(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
+    """:func:`fft2_kernel`'s failover for a frame over the VMEM budget: row
+    pass, materialised corner turn, column pass — more HBM trips, but never
+    an overflow."""
+    interpret = _interpret_default() if interpret is None else interpret
+    x = jnp.asarray(x)
     f, h, w, lead = _frames(x)
-    if fft2_fits_vmem(h, w) and not _faults.vmem_exhausted(
-        "kernel.fused", kind="fft2d", h=h, w=w
-    ):
-        with obs.span("kernel.launch", kernel="repro_fft2_fused"):
-            return repro_fft2_kernel(x, radix=radix, interpret=interpret)
-    else:
-        # Frame working set exceeds VMEM: row pass, materialised corner
-        # turn, column pass — more HBM trips, but never an overflow.
-        _failover_event("fft2d", h, w, f, real=False)
-        re, im = _split(x)
-        re, im = re.reshape(f, h, w), im.reshape(f, h, w)
-        with obs.span("fft.rows"):
-            yr, yi = _fft_rows(re.reshape(f * h, w), im.reshape(f * h, w),
-                               radix=radix, interpret=interpret)
-        with obs.span("fft.columns"):
-            yr = yr.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
-            yi = yi.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
-            yr, yi = _fft_rows(yr, yi, radix=radix, interpret=interpret)
-            yr = yr.reshape(f, w, h).swapaxes(-1, -2)
-            yi = yi.reshape(f, w, h).swapaxes(-1, -2)
+    _failover_event("fft2d", h, w, f, real=False)
+    re, im = _split(x)
+    re, im = re.reshape(f, h, w), im.reshape(f, h, w)
+    with obs.span("fft.rows"):
+        yr, yi = _fft_rows(re.reshape(f * h, w), im.reshape(f * h, w),
+                           radix=radix, interpret=interpret)
+    with obs.span("fft.columns"):
+        yr = yr.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
+        yi = yi.reshape(f, h, w).swapaxes(-1, -2).reshape(f * w, h)
+        yr, yi = _fft_rows(yr, yi, radix=radix, interpret=interpret)
+        yr = yr.reshape(f, w, h).swapaxes(-1, -2)
+        yi = yi.reshape(f, w, h).swapaxes(-1, -2)
     with obs.span("kernel.assemble"):
         return (yr + 1j * yi).reshape(*lead, h, w)
 
@@ -301,36 +309,37 @@ def rfft2_kernel(x: jax.Array, *, radix: int = 2, interpret: bool | None = None)
     """Real-input fused 2D FFT of (..., H, W) -> (..., H, W/2+1) complex."""
     interpret = _interpret_default() if interpret is None else interpret
     x = jnp.asarray(x)
+    if not fft2_fits_vmem(x.shape[-2], x.shape[-1], arrays=_REAL2D_ARRAYS):
+        return rfft2_unfused(x, radix=radix, interpret=interpret)
+    with obs.span("kernel.launch", kernel="repro_rfft2_fused"):
+        return repro_rfft2_kernel(x, radix=radix, interpret=interpret)
+
+
+def rfft2_unfused(x: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
+    """:func:`rfft2_kernel`'s failover: row rfft kernel, corner turn in
+    HBM, column FFT. The column batch (f·(W/2+1) rows) is odd, which would
+    force the fused kernel to a degenerate 1-row tile — the jnp engine's
+    pass program handles that pass instead."""
+    interpret = _interpret_default() if interpret is None else interpret
+    x = jnp.asarray(x)
     f, h, w, lead = _frames(x)
-    if fft2_fits_vmem(h, w, arrays=_REAL2D_ARRAYS) and not _faults.vmem_exhausted(
-        "kernel.fused", kind="rfft2d", h=h, w=w
-    ):
-        with obs.span("kernel.launch", kernel="repro_rfft2_fused"):
-            return repro_rfft2_kernel(x, radix=radix, interpret=interpret)
-    else:
-        # Unfused failover: row rfft kernel, corner turn in HBM, column FFT.
-        # The column batch (f·(W/2+1) rows) is odd, which would force the
-        # fused kernel to a degenerate 1-row tile — the jnp engine handles
-        # that pass instead.
-        _failover_event("rfft2d", h, w, f, real=True)
-        from repro.core.fft1d import fft_impl  # lazy: core imports kernels
-
-        xf = x.astype(jnp.float32).reshape(f, h, w)
-        half = w // 2 + 1
-        with obs.span("fft.rows"):
-            if fft_fits_vmem(w):
-                yr, yi = rfft_fused(xf.reshape(f * h, w), radix=radix,
-                                    interpret=interpret)
-                z = (yr + 1j * yi).reshape(f, h, half)
-            else:
-                from repro.core.rfft import rfft_impl  # rows too long for any tile
-
-                z = rfft_impl(xf.reshape(f * h, w), variant=_jnp_variant(radix))
-                z = z.reshape(f, h, half)
-        with obs.span("fft.columns"):
-            z = fft_impl(z.swapaxes(-1, -2), variant=_jnp_variant(radix))
-            z = z.swapaxes(-1, -2)
-            return z.reshape(*lead, h, half)
+    _failover_event("rfft2d", h, w, f, real=True)
+    xf = x.astype(jnp.float32).reshape(f, h, w)
+    half = w // 2 + 1
+    variant = _jnp_variant(radix)
+    with obs.span("fft.rows"):
+        if fft_fits_vmem(w):
+            yr, yi = rfft_fused(xf.reshape(f * h, w), radix=radix,
+                                interpret=interpret)
+            z = (yr + 1j * yi).reshape(f, h, half)
+        else:  # rows too long for any tile
+            z = repro_jnp_rfft_pass(xf.reshape(f * h, w), axis=-1, variant=variant)
+            z = z.reshape(f, h, half)
+    with obs.span("fft.columns"):
+        z = repro_jnp_fft_pass(z.swapaxes(-1, -2), axis=-1, variant=variant,
+                               inverse=False)
+        z = z.swapaxes(-1, -2)
+        return z.reshape(*lead, h, half)
 
 
 @_program
@@ -347,31 +356,32 @@ def irfft2_kernel(y: jax.Array, *, radix: int = 2, interpret: bool | None = None
     """Inverse of :func:`rfft2_kernel`: (..., H, W/2+1) -> real (..., H, W)."""
     interpret = _interpret_default() if interpret is None else interpret
     y = jnp.asarray(y)
+    if not fft2_fits_vmem(y.shape[-2], 2 * (y.shape[-1] - 1), arrays=_REAL2D_ARRAYS):
+        return irfft2_unfused(y, radix=radix, interpret=interpret)
+    return repro_irfft2_kernel(y, radix=radix, interpret=interpret)
+
+
+def irfft2_unfused(y: jax.Array, *, radix: int = 2, interpret: bool | None = None) -> jax.Array:
+    """:func:`irfft2_kernel`'s failover: column IFFT on the jnp engine's
+    pass program (the odd f·(W/2+1) column batch defeats the fused kernel's
+    row tiling), then the fused row irfft."""
+    interpret = _interpret_default() if interpret is None else interpret
+    y = jnp.asarray(y)
     f, h, half, lead = _frames(y)
     w = 2 * (half - 1)
-    if fft2_fits_vmem(h, w, arrays=_REAL2D_ARRAYS) and not _faults.vmem_exhausted(
-        "kernel.fused", kind="irfft2d", h=h, w=w
-    ):
-        return repro_irfft2_kernel(y, radix=radix, interpret=interpret)
-    else:
-        # Column IFFT via the jnp engine (the odd f·(W/2+1) column batch
-        # defeats the fused kernel's row tiling), then the fused row irfft.
-        _failover_event("irfft2d", h, w, f, real=True)
-        re, im = _split(y)
-        re, im = re.reshape(f, h, half), im.reshape(f, h, half)
-        from repro.core.fft1d import ifft_impl  # lazy: core imports kernels
-
-        z = ifft_impl((re + 1j * im).swapaxes(-1, -2), variant=_jnp_variant(radix))
-        z = z.swapaxes(-1, -2)
-        if fft_fits_vmem(w):
-            fr = jnp.real(z).astype(jnp.float32).reshape(f * h, half)
-            fi = jnp.imag(z).astype(jnp.float32).reshape(f * h, half)
-            out = irfft_fused(fr, fi, radix=radix, interpret=interpret)
-        else:
-            from repro.core.rfft import irfft_impl  # rows too long for any tile
-
-            out = irfft_impl(z.reshape(f * h, half), variant=_jnp_variant(radix))
-        out = out.reshape(f, h, w)
+    _failover_event("irfft2d", h, w, f, real=True)
+    variant = _jnp_variant(radix)
+    re, im = _split(y)
+    re, im = re.reshape(f, h, half), im.reshape(f, h, half)
+    z = repro_jnp_fft_pass((re + 1j * im).swapaxes(-1, -2), axis=-1,
+                           variant=variant, inverse=True)
+    z = z.swapaxes(-1, -2)
+    if fft_fits_vmem(w):
+        fr = jnp.real(z).astype(jnp.float32).reshape(f * h, half)
+        fi = jnp.imag(z).astype(jnp.float32).reshape(f * h, half)
+        out = irfft_fused(fr, fi, radix=radix, interpret=interpret)
+    else:  # rows too long for any tile
+        out = repro_jnp_irfft_pass(z.reshape(f * h, half), axis=-1, variant=variant)
     return out.reshape(*lead, h, w)
 
 

@@ -24,7 +24,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.spectral import fourier_mixing
+from repro.spectral import fourier_mixing
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod

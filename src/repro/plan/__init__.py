@@ -27,16 +27,6 @@ from repro.plan.plan import (
 )
 
 
-def __getattr__(name: str):
-    # Deprecation alias (see repro.plan.plan.__getattr__): the engine list
-    # lives in the repro.engines registry now; this stays importable for
-    # pre-registry callers and always reflects the live registry.
-    if name == "PLAN_VARIANTS":
-        from repro.plan.plan import PLAN_VARIANTS
-
-        return PLAN_VARIANTS
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
 __all__ = [
     "FFTPlan",
     "ProblemKey",
@@ -45,7 +35,6 @@ __all__ = [
     "KINDS",
     "NORMS",
     "PLAN_SCHEMA_VERSION",
-    "PLAN_VARIANTS",
     "PRECISIONS",
     "chunk_candidates",
     "default_cache",

@@ -2,8 +2,7 @@
 
 ``plan_fft`` is the explicit front door (pick a mode, get a plan, it is
 cached — and persisted when the cache is file-backed). ``resolve_call``
-is the implicit one: every ``repro.xfft`` transform and every
-``variant="auto"`` call site in ``repro.core`` funnels through it, so a
+is the implicit one: every ``repro.xfft`` transform funnels through it, so a
 warm cache (e.g. MEASURE plans produced at service startup or by
 ``benchmarks/plan_autotune.py``) steers the hot path while a cold cache
 falls back to the analytic ESTIMATE model. ``resolve_call`` is also
@@ -378,10 +377,9 @@ def resolve(
     cache: Optional[PlanCache] = None,
     direction: str = "fwd",
 ) -> FFTPlan:
-    """Cheap plan lookup for ``variant="auto"`` call sites (trace-safe).
-
-    Pre-xfft spelling of :func:`resolve_call` under the kind's canonical
-    axes; kept so bare-problem callers read naturally.
+    """Cheap plan lookup for a bare problem (trace-safe): :func:`resolve_call`
+    under the kind's canonical axes, for callers that pair it with
+    :func:`execute`.
     """
     return resolve_call(kind, shape, dtype, n_devices, cache, direction)
 
@@ -398,46 +396,32 @@ def execute(plan: FFTPlan, x, mesh=None, axis: str = "data"):
     (:func:`repro.resilience.run_plan`): an engine failure is quarantined
     and the call retries the next-best healthy rung instead of raising.
     The oaconv2d composite dispatches directly — its transforms ladder on
-    their own.
+    their own. The engine runs through :func:`repro.engines.apply_engine`,
+    or through its spec's op where a plan field is passed (a stream's
+    ``unroll``, a pencil's ``chunks``).
     """
+    from repro.engines import apply_engine, get_engine, op_takes  # lazy: leaf layer
+
     kind = plan.key.kind
-    inv = plan.key.direction == "inv"
-    if kind == "fft1d":
-        from repro.core.fft1d import fft_impl, ifft_impl
-
-        impl = ifft_impl if inv else fft_impl
-        return run_plan(plan, lambda v: impl(x, variant=v))
-    if kind == "fft2d":
-        from repro.core.fft2d import fft2_impl, ifft2_impl
-
-        impl = ifft2_impl if inv else fft2_impl
-        return run_plan(plan, lambda v: impl(x, variant=v))
-    if kind == "rfft1d":
-        from repro.core.rfft import irfft_impl, rfft_impl
-
-        impl = irfft_impl if inv else rfft_impl
-        return run_plan(plan, lambda v: impl(x, variant=v))
-    if kind == "rfft2d":
-        from repro.core.rfft import irfft2_impl, rfft2_impl
-
-        impl = irfft2_impl if inv else rfft2_impl
-        return run_plan(plan, lambda v: impl(x, variant=v))
+    direction = plan.key.direction
+    if kind in ("fft1d", "fft2d", "rfft1d", "rfft2d"):
+        return run_plan(plan, lambda v: apply_engine(v, kind, x, direction=direction))
     if kind == "fft2d_stream":
-        from repro.core.fft2d import fft2_stream
 
-        return run_plan(
-            plan, lambda v: fft2_stream(x, variant=v, unroll=plan.unroll)
-        )
+        def stream(v):
+            op = get_engine(v).op(kind)
+            return op(x, unroll=plan.unroll) if op_takes(op, "unroll") else op(x)
+
+        return run_plan(plan, stream)
     if kind == "fft2d_pencil":
         if mesh is None:
             raise ValueError("execute() needs mesh=... for a pencil plan")
         import jax
 
         from repro.core.distributed import pencil_sharding
-        from repro.engines import get_engine
 
         x = jax.device_put(x, pencil_sharding(mesh, axis, plan.key.layout, x.ndim))
-        return run_plan(plan, lambda v: get_engine(v).op(kind, plan.key.direction)(
+        return run_plan(plan, lambda v: get_engine(v).op(kind, direction)(
             x, chunks=plan.chunks))
     if kind == "oaconv2d":
         from repro.imaging.tiled import oaconvolve2

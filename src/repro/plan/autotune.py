@@ -45,8 +45,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.fft1d import butterfly_counts
-from repro.core.spectral import _next_pow2
+from repro.core.fft1d import _next_pow2, butterfly_counts
 from repro.launch.roofline import Roofline
 from repro.plan.plan import FFTPlan, ProblemKey
 from repro.resilience import faults as _faults
@@ -444,31 +443,22 @@ def _candidate_runners(key: ProblemKey) -> Dict[Tuple[str, int], Callable]:
 
     import jax
 
-    from repro.core.fft1d import fft_impl, ifft_impl
-    from repro.core.fft2d import fft2_impl, fft2_stream, ifft2_impl
-    from repro.core.rfft import irfft2_impl, irfft_impl, rfft2_impl, rfft_impl
-
-    inv = key.direction == "inv"
-    entry = {
-        "fft1d": ifft_impl if inv else fft_impl,
-        "fft2d": ifft2_impl if inv else fft2_impl,
-        "rfft1d": irfft_impl if inv else rfft_impl,
-        "rfft2d": irfft2_impl if inv else rfft2_impl,
-    }
-    from repro.core.fft1d import BUILTIN_VARIANTS
+    from repro.engines import apply_engine, get_engine, op_takes  # lazy: leaf layer
 
     runners: Dict[Tuple[str, int], Callable] = {}
     for v in variant_candidates(key):
-        if key.kind in entry:
-            runners[(v, 1)] = jax.jit(functools.partial(entry[key.kind], variant=v))
+        if key.kind in ("fft1d", "fft2d", "rfft1d", "rfft2d"):
+            runners[(v, 1)] = jax.jit(functools.partial(
+                apply_engine, v, key.kind, direction=key.direction))
         elif key.kind == "fft2d_stream":
-            # The scan-unroll knob only exists on the builtin jnp stream;
-            # registry engines run their own stream op and would time the
-            # identical computation twice under two labels.
-            for u in (1, 2) if v in BUILTIN_VARIANTS else (1,):
-                runners[(v, u)] = jax.jit(
-                    functools.partial(fft2_stream, variant=v, unroll=u)
-                )
+            # Only an op with the scan-unroll knob is timed per unroll; any
+            # other would time the identical computation under two labels.
+            op = get_engine(v).op(key.kind)
+            if op_takes(op, "unroll"):
+                for u in (1, 2):
+                    runners[(v, u)] = jax.jit(functools.partial(op, unroll=u))
+            else:
+                runners[(v, 1)] = jax.jit(op)
         else:
             raise ValueError(
                 f"MEASURE planning is unavailable for kind {key.kind!r} "

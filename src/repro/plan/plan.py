@@ -329,14 +329,6 @@ def problem_key(
 
 
 def __getattr__(name: str):
-    # Deprecation alias: the hardcoded engine tuple became the registry
-    # (``repro.engines``). Derived live so third-party registrations show
-    # up; restricted to single precision so pre-registry callers see
-    # exactly the engine population the old tuple named.
-    if name == "PLAN_VARIANTS":
-        from repro.engines import registered_variants
-
-        return registered_variants(precision="single")
     # Lazy re-export: the precision domain lives on the engine registry
     # (the leaf module) so registration and key validation share it.
     if name == "PRECISIONS":

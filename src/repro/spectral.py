@@ -1,4 +1,8 @@
-"""LM-facing applications of the paper's FFT engine.
+"""LM-facing applications of the paper's FFT engine, on the front door.
+
+Every transform here goes through ``repro.xfft``, so each call is planned
+and served through the degradation ladder like any other application;
+pin an engine with ``repro.xfft.config(variant=...)``.
 
 * ``fourier_mixing`` — FNet-style token mixing: Re(FFT2(x)) over (seq, d).
   FNet's mixing sublayer *is* a 2D Fourier transform, so the paper's
@@ -20,15 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.fft1d import Variant, fft_impl, ifft_impl
-from repro.core.fft2d import fft2_impl, ifft2_impl
-from repro.core.rfft import irfft2_impl, irfft_impl, rfft2_impl, rfft_impl
+from repro import xfft
+from repro.core.fft1d import _next_pow2
+from repro.core.rfft import _is_real
 
 __all__ = ["fourier_mixing", "fftconv", "correlate2", "stft", "log_mel"]
-
-
-def _is_real(x) -> bool:
-    return not jnp.issubdtype(jnp.asarray(x).dtype, jnp.complexfloating)
 
 
 def fourier_mixing(x: jax.Array, variant: str = "auto") -> jax.Array:
@@ -37,31 +37,24 @@ def fourier_mixing(x: jax.Array, variant: str = "auto") -> jax.Array:
     x: (..., seq, d) real. Both dims must be powers of two (pad upstream).
     variant="rfft" uses the real-input specialisation (beyond-paper
     optimization, §Perf cell C): ~2× fewer FLOPs/bytes via conjugate
-    symmetry.
+    symmetry; "auto" lets the planner pick the complex 2D transform's
+    engine, and an engine name pins it.
     """
     if variant == "rfft":
         return fourier_mixing_rfft(x)
-    return jnp.real(fft2_impl(x.astype(jnp.complex64), variant=variant)).astype(x.dtype)
+    with xfft.config(variant=None if variant == "auto" else variant):
+        return jnp.real(xfft.fft2(x.astype(jnp.complex64))).astype(x.dtype)
 
 
-def rfft_last_axis(x: jax.Array, variant: Variant = "auto") -> jax.Array:
-    """Real-input FFT along the last axis via the packed half-length trick:
-    one complex FFT of length D/2 + O(D) untangling, instead of length D.
-    Returns the non-redundant half spectrum (..., D//2 + 1).
-
-    Thin alias of :func:`repro.core.rfft.rfft` (kept for back-compat)."""
-    return rfft_impl(x, axis=-1, variant=variant)
-
-
-def fourier_mixing_rfft(x: jax.Array, variant: Variant = "auto") -> jax.Array:
+def fourier_mixing_rfft(x: jax.Array) -> jax.Array:
     """Re(FFT_seq(FFT_d(x))) for real x, computing only the non-redundant
     half of the d-spectrum and mirroring the real part back:
 
       Re(Y)[s, k] = Re(Y)[(S−s) mod S, D−k]   for k > D/2
     """
     s, d = x.shape[-2], x.shape[-1]
-    xh = rfft_last_axis(x, variant=variant)        # (..., S, D/2+1)
-    yh = fft_impl(xh, axis=-2, variant=variant)         # seq-axis complex FFT
+    xh = xfft.rfft(x, axis=-1)                     # (..., S, D/2+1)
+    yh = xfft.fft(xh, axis=-2)                     # seq-axis complex FFT
     re = jnp.real(yh)
     s_mirror = (-jnp.arange(s)) % s
     tail_k = jnp.arange(d // 2 - 1, 0, -1)         # D−k for k = D/2+1 .. D−1
@@ -69,14 +62,7 @@ def fourier_mixing_rfft(x: jax.Array, variant: Variant = "auto") -> jax.Array:
     return jnp.concatenate([re, tail], axis=-1).astype(x.dtype)
 
 
-def _next_pow2(n: int) -> int:
-    """Power-of-two cover of ``n``, floored at 2 (the engines' minimum
-    transform length). Shared by fftconv, the imaging tiled-convolution
-    padding and the planner's oaconv2d tile sweep."""
-    return max(2, 1 << max(int(n) - 1, 0).bit_length())
-
-
-def fftconv(x: jax.Array, kernel: jax.Array, variant: Variant = "auto") -> jax.Array:
+def fftconv(x: jax.Array, kernel: jax.Array) -> jax.Array:
     """Causal long convolution y[t] = sum_s k[s]·x[t−s] via the FFT engine.
 
     x: (..., seq, d); kernel: (seq_k, d) with seq_k <= seq. O(L log L) versus
@@ -91,20 +77,13 @@ def fftconv(x: jax.Array, kernel: jax.Array, variant: Variant = "auto") -> jax.A
     xp = jnp.pad(xt, [(0, 0)] * (xt.ndim - 1) + [(0, n - seq)])
     kp = jnp.pad(kt, [(0, 0)] * (kt.ndim - 1) + [(0, n - kt.shape[-1])])
     if _is_real(x) and _is_real(kernel):
-        y = irfft_impl(
-            rfft_impl(xp, variant=variant) * rfft_impl(kp, variant=variant),
-            variant=variant,
-        )[..., :seq]
+        y = xfft.irfft(xfft.rfft(xp) * xfft.rfft(kp))[..., :seq]
         return jnp.swapaxes(y, -1, -2).astype(x.dtype)
-    y = ifft_impl(
-        fft_impl(xp, variant=variant) * fft_impl(kp, variant=variant),
-        variant=variant,
-    )[..., :seq]
+    y = xfft.ifft(xfft.fft(xp) * xfft.fft(kp))[..., :seq]
     return jnp.swapaxes(jnp.real(y), -1, -2).astype(x.dtype)
 
 
-def correlate2(scene: jax.Array, template: jax.Array,
-               variant: Variant = "auto") -> jax.Array:
+def correlate2(scene: jax.Array, template: jax.Array) -> jax.Array:
     """Matched-filter cross-correlation entirely in the Fourier domain:
 
         corr = IFFT2( FFT2(scene) · conj(FFT2(template)) )
@@ -116,12 +95,12 @@ def correlate2(scene: jax.Array, template: jax.Array,
     HBM traffic of the complex transform.
     """
     if _is_real(scene) and _is_real(template):
-        fs = rfft2_impl(scene, variant=variant)
-        ft = rfft2_impl(template, variant=variant)
-        return irfft2_impl(fs * jnp.conj(ft), variant=variant)
-    fs = fft2_impl(jnp.asarray(scene).astype(jnp.complex64), variant=variant)
-    ft = fft2_impl(jnp.asarray(template).astype(jnp.complex64), variant=variant)
-    return jnp.real(ifft2_impl(fs * jnp.conj(ft), variant=variant))
+        fs = xfft.rfft2(scene)
+        ft = xfft.rfft2(template)
+        return xfft.irfft2(fs * jnp.conj(ft))
+    fs = xfft.fft2(jnp.asarray(scene).astype(jnp.complex64))
+    ft = xfft.fft2(jnp.asarray(template).astype(jnp.complex64))
+    return jnp.real(xfft.ifft2(fs * jnp.conj(ft)))
 
 
 @functools.lru_cache(maxsize=8)
@@ -129,18 +108,13 @@ def _hann(n: int) -> np.ndarray:
     return (0.5 - 0.5 * np.cos(2 * np.pi * np.arange(n) / n)).astype(np.float32)
 
 
-def stft(
-    audio: jax.Array,
-    frame: int = 512,
-    hop: int = 256,
-    variant: Variant = "auto",
-) -> jax.Array:
+def stft(audio: jax.Array, frame: int = 512, hop: int = 256) -> jax.Array:
     """Short-time Fourier transform: (..., T) -> (..., frames, frame//2+1)."""
     t = audio.shape[-1]
     n_frames = 1 + (t - frame) // hop
     idx = np.arange(frame)[None, :] + hop * np.arange(n_frames)[:, None]
     windows = audio[..., idx] * jnp.asarray(_hann(frame))
-    spec = fft_impl(windows.astype(jnp.complex64), variant=variant)
+    spec = xfft.fft(windows.astype(jnp.complex64))
     return spec[..., : frame // 2 + 1]
 
 
@@ -172,10 +146,9 @@ def log_mel(
     frame: int = 512,
     hop: int = 256,
     n_mels: int = 80,
-    variant: Variant = "auto",
 ) -> jax.Array:
     """Whisper-style log-mel spectrogram built on the paper's engine."""
-    spec = stft(audio, frame=frame, hop=hop, variant=variant)
+    spec = stft(audio, frame=frame, hop=hop)
     power = jnp.abs(spec) ** 2
     fb = jnp.asarray(_mel_filterbank(frame // 2 + 1, n_mels))
     mel = jnp.einsum("...tf,mf->...tm", power, fb)

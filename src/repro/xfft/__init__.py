@@ -35,8 +35,10 @@ Engine selection is scoped, not threaded::
         y = xfft.rfft2(frames)              # ...only inside this scope
     xfft.config(mode="measure")             # tune-on-miss, process-wide
 
-The old ``repro.core`` entry points (``repro.core.fft`` etc.) remain as
-deprecation shims that warn once and delegate here.
+Layers, top to bottom: ``xfft`` (this front door) -> ``plan`` (resolve)
+-> ``resilience.ladder`` (``run_plan``) -> ``engines`` (the registry: name
+-> op) -> ``kernels`` (Pallas entry programs) and ``core`` (jnp pass
+programs, 2D compositions, the pencil program).
 """
 
 from repro.xfft._config import XFFTConfig, config, get_config

@@ -9,10 +9,11 @@ Every function here follows the same dispatch pipeline:
    (plan cache -> scoped config overrides -> concrete engine from the
    ``repro.engines`` registry, capability-filtered by the scope's
    precision and backend restriction);
-4. run the ``repro.core`` engine implementation under that variant,
-   through the resilience degradation ladder
-   (:func:`repro.resilience.run_plan`): an engine failure quarantines
-   the engine for this problem key and retries the next-best rung;
+4. run the planned engine through :func:`repro.engines.apply_engine`
+   (the one place an engine name becomes code), under the resilience
+   degradation ladder (:func:`repro.resilience.run_plan`): an engine
+   failure quarantines the engine for this problem key and retries the
+   next-best rung;
 5. apply the ``norm`` scaling on top of the engines' native convention
    (forward unscaled, inverse 1/N — i.e. ``"backward"``).
 
@@ -53,18 +54,11 @@ from repro._x64 import enable_x64 as _enable_x64
 
 from repro.core.fft1d import _check_pow2 as _core_check_pow2
 from repro.core.fft1d import canonical_axis
-from repro.core.fft1d import fft_impl as _fft_impl
-from repro.core.fft1d import ifft_impl as _ifft_impl
-from repro.core.fft2d import fft2_impl as _fft2_impl
 from repro.core.fft2d import fftshift2 as _core_fftshift2
-from repro.core.fft2d import ifft2_impl as _ifft2_impl
 from repro.core.fft2d import ifftshift2 as _core_ifftshift2
 from repro.core.distributed import pencil_layout as _pencil_layout
 from repro.core.rfft import _ensure_real  # one real-input contract
-from repro.core.rfft import irfft2_impl as _irfft2_impl
-from repro.core.rfft import irfft_impl as _irfft_impl
-from repro.core.rfft import rfft2_impl as _rfft2_impl
-from repro.core.rfft import rfft_impl as _rfft_impl
+from repro.engines import apply_engine as _apply_engine
 from repro.engines import get_engine as _get_engine
 from repro.plan.api import resolve_call
 from repro.plan.plan import NORMS
@@ -204,7 +198,7 @@ def fft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None):
     length = x.shape[ax]
     _check_pow2(length, ax, "fft")
     plan = resolve_call("fft1d", _moved_shape(x.shape, ax))
-    y = _run_plan(plan, lambda v: _fft_impl(x, axis=ax, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "fft1d", x, axis=ax))
     return _scale(y, norm, length, forward=True)
 
 
@@ -219,7 +213,8 @@ def ifft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None)
     length = x.shape[ax]
     _check_pow2(length, ax, "ifft")
     plan = resolve_call("fft1d", _moved_shape(x.shape, ax), direction="inv")
-    y = _run_plan(plan, lambda v: _ifft_impl(x, axis=ax, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "fft1d", x, direction="inv",
+                                                axis=ax))
     return _scale(y, norm, length, forward=False)
 
 
@@ -258,9 +253,9 @@ def _complex_2d(x, direction: str):
     any other input on the single-device plan (engines' backward norm)."""
     sharded = _pencil_layout(x)
     if sharded is None:
-        impl = _ifft2_impl if direction == "inv" else _fft2_impl
         plan = resolve_call("fft2d", x.shape, direction=direction)
-        return _run_plan(plan, lambda v: impl(x, variant=v))
+        return _run_plan(plan, lambda v: _apply_engine(v, "fft2d", x,
+                                                       direction=direction))
     mesh, axis, layout = sharded
     obs.count("xfft.sharded_calls")
     plan = resolve_call("fft2d_pencil", x.shape, n_devices=mesh.shape[axis],
@@ -355,7 +350,7 @@ def rfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None)
     length = x.shape[ax]
     _check_pow2(length, ax, "rfft")
     plan = resolve_call("rfft1d", _moved_shape(x.shape, ax), dtype="float32")
-    y = _run_plan(plan, lambda v: _rfft_impl(x, axis=ax, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "rfft1d", x, axis=ax))
     return _scale(y, norm, length, forward=True)
 
 
@@ -372,7 +367,8 @@ def irfft(x, n: Optional[int] = None, axis: int = -1, norm: Optional[str] = None
     x = _resize_axis(x, length // 2 + 1, ax)
     key_shape = _moved_shape(x.shape, ax)[:-1] + (length,)
     plan = resolve_call("rfft1d", key_shape, dtype="float32", direction="inv")
-    y = _run_plan(plan, lambda v: _irfft_impl(x, axis=ax, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "rfft1d", x, direction="inv",
+                                                axis=ax))
     return _scale(y, norm, length, forward=False)
 
 
@@ -383,7 +379,7 @@ def rfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     x, norm, canon, moved = _prep_2d(x, s, axes, norm, "rfft2")
     h, w = x.shape[-2], x.shape[-1]
     plan = resolve_call("rfft2d", x.shape, dtype="float32")
-    y = _run_plan(plan, lambda v: _rfft2_impl(x, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "rfft2d", x))
     return _unmove_2d(_scale(y, norm, h * w, forward=True), canon, moved)
 
 
@@ -410,7 +406,7 @@ def irfft2(x, s=None, axes=(-2, -1), norm: Optional[str] = None):
     plan = resolve_call(
         "rfft2d", x.shape[:-1] + (w,), dtype="float32", direction="inv"
     )
-    y = _run_plan(plan, lambda v: _irfft2_impl(x, variant=v))
+    y = _run_plan(plan, lambda v: _apply_engine(v, "rfft2d", x, direction="inv"))
     return _unmove_2d(_scale(y, norm, h * w, forward=False), canon, moved)
 
 

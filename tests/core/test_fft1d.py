@@ -8,15 +8,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.xfft as xfft
 from repro.core.fft1d import (
     bit_reversal_permutation,
     butterfly_counts,
-    fft,
     fft_routing_tables,
-    ifft,
 )
 
 VARIANTS = ("looped", "unrolled", "stockham")
+
+
+def fft(x, axis=-1, variant=None):
+    """The front door's fft, on engine ``variant`` (None: the planner's)."""
+    with xfft.config(variant=variant):
+        return xfft.fft(x, axis=axis)
 
 
 def _crand(rng, shape):

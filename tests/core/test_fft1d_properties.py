@@ -13,7 +13,7 @@ import jax.numpy as jnp  # noqa: E402
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
-from repro.core.fft1d import fft, ifft  # noqa: E402
+from repro.xfft import fft, ifft  # noqa: E402
 
 
 def _crand(rng, shape):

@@ -1,10 +1,32 @@
 """2D engine + ping-pong streaming pipeline tests."""
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.fft2d import fft2, fft2_stream, fftshift2, ifft2
+import repro.xfft as xfft
+from repro.core.fft2d import fftshift2
+from repro.plan import execute, resolve
+
+
+def fft2(x, variant=None):
+    """The front door's fft2, on engine ``variant`` (None: the planner's)."""
+    with xfft.config(variant=variant):
+        return xfft.fft2(x)
+
+
+def ifft2(x):
+    return xfft.ifft2(x)
+
+
+def fft2_stream(frames, unroll=None):
+    """The planned ping-pong stream (``unroll`` pins the scan unroll)."""
+    plan = resolve("fft2d_stream", frames.shape)
+    if unroll is not None:
+        plan = dataclasses.replace(plan, unroll=unroll)
+    return execute(plan, frames)
 
 
 @pytest.mark.parametrize("hw", [(8, 8), (16, 32), (64, 64)])
@@ -60,9 +82,9 @@ def test_stream_unrolled_scan_matches(rng, unroll):
 
 
 def test_stream_auto_plan(rng):
-    """variant="auto"/unroll="auto" resolve through repro.plan and stay exact."""
+    """The stream's engine and unroll resolve through repro.plan and stay exact."""
     frames = rng.standard_normal((4, 8, 8)).astype(np.float32)
-    got = np.asarray(fft2_stream(jnp.asarray(frames), variant="auto", unroll="auto"))
+    got = np.asarray(fft2_stream(jnp.asarray(frames)))
     np.testing.assert_allclose(got, np.fft.fft2(frames), atol=1e-4)
 
     from repro.plan import default_cache, problem_key

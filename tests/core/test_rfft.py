@@ -4,9 +4,32 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.rfft import irfft, irfft2, rfft, rfft2
+import repro.xfft as xfft
 
 VARIANTS = ["looped", "unrolled", "stockham", "radix4"]
+
+
+def _on(variant, transform, x, **kw):
+    """``transform`` through the front door on engine ``variant`` (None or
+    "auto": the planner's)."""
+    with xfft.config(variant=None if variant == "auto" else variant):
+        return transform(x, **kw)
+
+
+def rfft(x, axis=-1, variant=None):
+    return _on(variant, xfft.rfft, x, axis=axis)
+
+
+def irfft(y, axis=-1, variant=None):
+    return _on(variant, xfft.irfft, y, axis=axis)
+
+
+def rfft2(x, variant=None):
+    return _on(variant, xfft.rfft2, x)
+
+
+def irfft2(y, variant=None):
+    return _on(variant, xfft.irfft2, y)
 
 
 @pytest.mark.parametrize("shape", [(1, 2), (3, 8), (2, 64), (4, 128), (1, 1024)])
@@ -56,7 +79,7 @@ def test_irfft2_roundtrip(rng, hw, variant):
 
 
 def test_rfft_auto_plans_under_real_kind(rng):
-    """variant="auto" resolves rfft through the rfft1d problem kind."""
+    """An unpinned rfft resolves through the rfft1d problem kind."""
     from repro.plan import default_cache, problem_key
 
     x = rng.standard_normal((4, 32)).astype(np.float32)

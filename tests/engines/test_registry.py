@@ -1,5 +1,5 @@
-"""The repro.engines registry: registration API, capability filtering,
-the registry-derived PLAN_VARIANTS alias, and dynamic error messages."""
+"""The repro.engines registry: registration API, capability filtering
+and dynamic error messages."""
 
 import dataclasses
 
@@ -64,9 +64,9 @@ def test_register_rejects_duplicates_and_bad_specs():
 
 
 def test_builtin_engines_cannot_be_replaced_or_removed():
-    """The six seed bodies are fused into the core dispatch chains — a
-    registry override would never execute, so registration refuses rather
-    than lying (register under a new name instead)."""
+    """Persisted wisdom, the ladder's bottom rung and the fused kernels'
+    failovers all mean the six seed engines by name, so registration
+    refuses to replace or remove one (register under a new name instead)."""
     spec = EngineSpec(name="stockham", backend="jnp", kinds=("fft1d",))
     with pytest.raises(ValueError, match="cannot be replaced"):
         engines.register_engine(spec)
@@ -141,24 +141,6 @@ def test_plan_validation_error_is_dynamic():
     # the message names the live registry, not a stale tuple
     assert "reference_x64" in str(ei.value)
     assert "registered engines" in str(ei.value)
-
-
-def test_plan_variants_alias_tracks_registry():
-    from repro.plan import PLAN_VARIANTS
-
-    assert PLAN_VARIANTS == engines.registered_variants(precision="single")
-    assert "reference_x64" not in PLAN_VARIANTS
-
-    @engines.engine("toy_alias_probe", backend="jnp", kinds=("fft1d",))
-    def toy_ops(kind, direction):
-        return lambda x: x
-
-    try:
-        from repro.plan import PLAN_VARIANTS as live
-
-        assert "toy_alias_probe" in live  # the alias is derived, not frozen
-    finally:
-        engines.unregister_engine("toy_alias_probe")
 
 
 def test_cache_keys_gain_precision_and_backend_scope():

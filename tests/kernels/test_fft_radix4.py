@@ -158,7 +158,7 @@ def test_fft2_kernel_failover_handles_untileable_rows(rng):
 
 def test_irfft_discards_dc_and_nyquist_imag(rng):
     """np.fft.irfft parity: Im(Y[0]) and Im(Y[N/2]) are ignored."""
-    from repro.core.rfft import irfft
+    from repro.xfft import irfft
 
     n = 16
     y = (rng.standard_normal((2, n // 2 + 1))

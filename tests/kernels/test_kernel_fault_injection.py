@@ -1,17 +1,28 @@
 """Injected VMEM exhaustion must drive the REAL unfused failover path.
 
-The fused 2D kernels consult the ``kernel.fused`` fault seam alongside
-their genuine VMEM census, so a chaos run exercises the row / corner-turn
-/ column failover on frames that would normally fit — same code path a
-too-big frame takes, no giant allocation needed.
+The fused engines consult the ``kernel.fused`` fault seam alongside the
+kernels' genuine VMEM census, so a chaos run exercises the row /
+corner-turn / column failover on frames that would normally fit — same
+code path a too-big frame takes, no giant allocation needed.
 """
 
+import jax.numpy as jnp
 import numpy as np
 
 import repro.xfft as xfft
 from repro import obs
-from repro.kernels.ops import fft2_kernel, rfft2_kernel
+from repro.engines import get_engine
 from repro.resilience import FaultPlan, FaultSpec
+
+
+def fft2_kernel(x):
+    """The ``fused`` engine's fft2d op: the fused kernel behind the seam."""
+    return get_engine("fused").op("fft2d")(jnp.asarray(x))
+
+
+def rfft2_kernel(x):
+    """The ``fused`` engine's rfft2d op."""
+    return get_engine("fused").op("rfft2d")(jnp.asarray(x))
 
 
 def _frame(rng, shape=(16, 16)):

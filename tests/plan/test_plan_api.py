@@ -1,15 +1,15 @@
-"""Planner API: ESTIMATE/MEASURE plans, variant="auto" numerical equivalence
-to the float64 DFT oracle, and execute() dispatch."""
+"""Planner API: ESTIMATE/MEASURE plans, planned calls' numerical
+equivalence to the float64 DFT oracle, and execute() dispatch."""
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from repro.core.fft1d import fft
-from repro.core.fft2d import fft2, fft2_stream
+import repro.xfft as xfft
+from repro.core.fft2d import fft2_stream
+from repro.engines import registered_variants
 from repro.plan import (
-    PLAN_VARIANTS,
     PlanCache,
     chunk_candidates,
     execute,
@@ -17,6 +17,11 @@ from repro.plan import (
     problem_key,
     resolve,
 )
+
+
+def _pinned(variant, transform, x):
+    with xfft.config(variant=variant):
+        return transform(x)
 
 
 def _dft_oracle(x, axes):
@@ -38,7 +43,7 @@ def test_estimate_plan_is_concrete_and_deterministic():
     cache = PlanCache()
     p1 = plan_fft("fft2d", (64, 64), cache=cache)
     p2 = plan_fft("fft2d", (64, 64), cache=cache)
-    assert p1.variant in PLAN_VARIANTS
+    assert p1.variant in registered_variants(precision="single")
     assert p1 is p2  # second call is a cache hit, not a re-plan
     assert cache.hits >= 1
 
@@ -67,7 +72,7 @@ def test_estimate_crossover_small_vs_large():
 
 def test_fft1d_auto_matches_float64_oracle(crand):
     x = crand((3, 128))
-    got = np.asarray(fft(jnp.asarray(x), variant="auto"))
+    got = np.asarray(xfft.fft(jnp.asarray(x)))
     ref = _dft_oracle(x, axes=(-1,))
     scale = max(1.0, np.max(np.abs(ref)))
     np.testing.assert_allclose(got / scale, ref / scale, atol=5e-6)
@@ -75,7 +80,7 @@ def test_fft1d_auto_matches_float64_oracle(crand):
 
 def test_fft2_auto_matches_float64_oracle(crand):
     x = crand((2, 32, 64))
-    got = np.asarray(fft2(jnp.asarray(x), variant="auto"))
+    got = np.asarray(xfft.fft2(jnp.asarray(x)))
     ref = _dft_oracle(x, axes=(-2, -1))
     scale = max(1.0, np.max(np.abs(ref)))
     np.testing.assert_allclose(got / scale, ref / scale, atol=1e-5)
@@ -83,7 +88,7 @@ def test_fft2_auto_matches_float64_oracle(crand):
 
 def test_fft2_stream_auto_matches_float64_oracle(crand):
     frames = crand((5, 16, 32))
-    got = np.asarray(fft2_stream(jnp.asarray(frames), variant="auto", unroll="auto"))
+    got = np.asarray(execute(resolve("fft2d_stream", frames.shape), jnp.asarray(frames)))
     ref = _dft_oracle(frames, axes=(-2, -1))
     scale = max(1.0, np.max(np.abs(ref)))
     np.testing.assert_allclose(got / scale, ref / scale, atol=1e-5)
@@ -100,7 +105,7 @@ def test_measure_plan_beats_nothing_but_is_concrete(crand):
         "fft1d", (2, 64), mode="measure", cache=cache, measure_iters=2,
         timings_out=timings,
     )
-    assert set(timings) == set(PLAN_VARIANTS)
+    assert set(timings) == set(registered_variants(precision="single"))
     assert plan.mode == "measure" and plan.measured_us is not None
     assert plan.measured_us == pytest.approx(min(timings.values()))
     assert cache.get(plan.key).mode == "measure"  # MEASURE displaced ESTIMATE
@@ -125,7 +130,7 @@ def test_execute_dispatch_matches_direct_calls(crand):
     p2 = plan_fft("fft2d", (32, 32), cache=cache)
     np.testing.assert_array_equal(
         np.asarray(execute(p2, jnp.asarray(x2))),
-        np.asarray(fft2(jnp.asarray(x2), variant=p2.variant)),
+        np.asarray(_pinned(p2.variant, xfft.fft2, jnp.asarray(x2))),
     )
     frames = crand((3, 16, 16))
     ps = plan_fft("fft2d_stream", (3, 16, 16), cache=cache)

@@ -9,17 +9,23 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core.fft2d import fft2, ifft2
-from repro.core.rfft import rfft2
+import repro.xfft as xfft
+from repro.engines import registered_variants
 from repro.plan import (
     PLAN_SCHEMA_VERSION,
-    PLAN_VARIANTS,
     PlanCache,
     plan_fft,
     problem_key,
     resolve,
     variant_candidates,
 )
+
+PLAN_VARIANTS = registered_variants(precision="single")
+
+
+def _pinned(variant, transform, x):
+    with xfft.config(variant=variant):
+        return transform(x)
 
 
 def test_new_variants_are_first_class():
@@ -86,7 +92,7 @@ def test_ifft2_auto_resolves_inverse_key(rng):
     x = (rng.standard_normal((16, 16)) + 1j * rng.standard_normal((16, 16))).astype(
         np.complex64
     )
-    got = np.asarray(ifft2(jnp.asarray(x), variant="auto"))
+    got = np.asarray(xfft.ifft2(jnp.asarray(x)))
     np.testing.assert_allclose(got, np.fft.ifft2(x), atol=1e-4)
     inv_key = problem_key("fft2d", (16, 16), direction="inv")
     assert default_cache().get(inv_key) is not None
@@ -98,14 +104,14 @@ def test_execute_variants_numerically_exact(rng, variant):
     x = (rng.standard_normal((32, 32)) + 1j * rng.standard_normal((32, 32))).astype(
         np.complex64
     )
-    got = np.asarray(fft2(jnp.asarray(x), variant=variant))
+    got = np.asarray(_pinned(variant, xfft.fft2, jnp.asarray(x)))
     ref = np.fft.fft2(x)
     scale = max(1.0, np.max(np.abs(ref)))
     np.testing.assert_allclose(got / scale, ref / scale, atol=1e-5)
     # planned rfft2 with an explicitly pinned variant matches numpy too
     xr = rng.standard_normal((32, 32)).astype(np.float32)
     np.testing.assert_allclose(
-        np.asarray(rfft2(jnp.asarray(xr), variant=variant)),
+        np.asarray(_pinned(variant, xfft.rfft2, jnp.asarray(xr))),
         np.fft.rfft2(xr),
         atol=1e-3,
     )
